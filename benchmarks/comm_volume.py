@@ -143,6 +143,9 @@ def measured_volumes(d: int = D, n: int = N_FLAT, n_in: int = N_INNER,
     separate XLA compile — ask only for what you read."""
     kinds = list(kinds or list_compressors())
     env = dict(os.environ)
+    # the child means the CPU's forced host devices, never a chip the
+    # parent's machine may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=" + \
         str(max(n, n_in * n_out))
     src = os.path.join(os.path.dirname(__file__), "..", "src")

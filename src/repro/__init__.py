@@ -1,8 +1,1 @@
-"""1-bit Adam reproduction (jax_pallas).
-
-Importing the package installs the JAX version-compat shims (see
-:mod:`repro.compat`) so all modules can target one API spelling.
-"""
-from repro import compat as _compat
-
-_compat.install()
+"""1-bit Adam reproduction (jax_pallas)."""

@@ -14,6 +14,7 @@ because ``error = input - decompress(compress(input))``.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Tuple
 
@@ -48,19 +49,49 @@ def padded_length(d: int, n_chunks: int, block_size: int = DEFAULT_BLOCK) -> int
     return ((d + q - 1) // q) * q
 
 
-_POW2 = 2 ** jnp.arange(8, dtype=jnp.uint8)
+def _lane_bytes() -> jax.Array:
+    """(128, 16) 0/1 bf16: lane l of a 128-element row is a bit of byte l // 8."""
+    return (jnp.arange(128)[:, None] // 8
+            == jnp.arange(16)[None, :]).astype(jnp.bfloat16)
 
 
 def pack_signs(x: jax.Array) -> jax.Array:
-    """(d,) float -> (d/8,) uint8 bitmap; bit j of byte i = sign(x[8i+j]) >= 0."""
-    bits = (x >= 0).astype(jnp.uint8).reshape(-1, 8)
-    return jnp.sum(bits * _POW2, axis=1, dtype=jnp.uint8)
+    """(d,) float -> (d/8,) uint8 bitmap; bit j of byte i = sign(x[8i+j]) >= 0.
+
+    The vector is viewed as rows of 128 elements, each row packing into
+    16 bytes: a lane's sign bit is weighted by ``2**(lane % 8)`` and a 0/1
+    matmul with :func:`_lane_bytes` sums every 8 consecutive lanes into
+    their byte.  No intermediate has a minor dimension of 8, which a TPU
+    pads to 128 lanes (16x the bytes).  The operands are powers of two and
+    0/1 in bf16 with f32 accumulation, so the bytes are exact.
+    """
+    d = x.shape[0]
+    pos = jnp.pad(x >= 0, (0, (-d) % 128)).reshape(-1, 128)
+    weight = jnp.left_shift(1, jnp.arange(128) % 8).astype(jnp.bfloat16)
+    bits = jnp.where(pos, weight, jnp.bfloat16(0))
+    byte = jnp.dot(bits, _lane_bytes(), preferred_element_type=jnp.float32)
+    return byte.astype(jnp.uint8).reshape(-1)[:d // 8]
 
 
 def unpack_signs(packed: jax.Array) -> jax.Array:
-    """(d/8,) uint8 -> (d,) float32 in {-1, +1}."""
-    bits = (packed[:, None] >> jnp.arange(8, dtype=jnp.uint8)) & jnp.uint8(1)
-    return (bits.astype(jnp.float32) * 2.0 - 1.0).reshape(-1)
+    """(d/8,) uint8 -> (d,) float32 in {-1, +1}; the mirror of
+    :func:`pack_signs`: the transposed matmul copies each byte onto its 8
+    lanes (one nonzero term per output, so bf16 is exact) and lane ``l``
+    keeps bit ``l % 8``."""
+    n = packed.shape[0]
+    rows = jnp.pad(packed, (0, (-n) % 16)).reshape(-1, 16)
+    byte = jnp.dot(rows.astype(jnp.bfloat16), _lane_bytes().T,
+                   preferred_element_type=jnp.bfloat16)
+    bit = jnp.right_shift(byte.astype(jnp.int32), jnp.arange(128) % 8) & 1
+    return (bit.astype(jnp.float32) * 2.0 - 1.0).reshape(-1)[:8 * n]
+
+
+def _row_width(block_size: int) -> int:
+    """Elements per row of the view the scales are taken on: 128 lanes
+    when the block allows.  A flat vector viewed as rows of 128 is its own
+    TPU layout, while a ``(d/block, block)`` view is a relayout copy of
+    the whole vector."""
+    return math.gcd(block_size, 128)
 
 
 def compress_onebit(x: jax.Array, block_size: int = DEFAULT_BLOCK,
@@ -75,8 +106,9 @@ def compress_onebit(x: jax.Array, block_size: int = DEFAULT_BLOCK,
     if use_kernel:
         from repro.kernels.onebit import ops as _kops
         return _kops.compress(x, block_size=block_size)
-    xb = x.reshape(-1, block_size)
-    scales = jnp.mean(jnp.abs(xb), axis=1)
+    w = _row_width(block_size)
+    row_sums = jnp.sum(jnp.abs(x).reshape(-1, w), axis=1)
+    scales = jnp.sum(row_sums.reshape(-1, block_size // w), axis=1) / block_size
     return pack_signs(x), scales
 
 
@@ -87,8 +119,24 @@ def decompress_onebit(packed: jax.Array, scales: jax.Array,
     if use_kernel:
         from repro.kernels.onebit import ops as _kops
         return _kops.decompress(packed, scales, block_size=block_size)
-    signs = unpack_signs(packed).reshape(-1, block_size)
-    return (signs * scales[:, None]).reshape(-1)
+    return _scaled(unpack_signs(packed), scales, block_size)
+
+
+def _scaled(signs: jax.Array, scales: jax.Array, block_size: int
+            ) -> jax.Array:
+    """``signs`` in {-1, +1} (or any per-element factor) times the scale
+    of each element's block, on the 128-lane row view."""
+    w = _row_width(block_size)
+    row_scales = jnp.repeat(scales, block_size // w)
+    return (signs.reshape(-1, w) * row_scales[:, None]).reshape(-1)
+
+
+def onebit_residual(x: jax.Array, scales: jax.Array,
+                    block_size: int = DEFAULT_BLOCK) -> jax.Array:
+    """``x - decompress_onebit(compress_onebit(x))``, bitwise, without
+    the round trip through the bitmap: the decompressed value of an
+    element is its block's scale with the sign bit ``x >= 0``."""
+    return x - _scaled(jnp.where(x >= 0, 1.0, -1.0), scales, block_size)
 
 
 def ef_compress(x: jax.Array, err: jax.Array, cfg: CompressionConfig
@@ -103,9 +151,7 @@ def ef_compress(x: jax.Array, err: jax.Array, cfg: CompressionConfig
     if cfg.kind == "identity":
         return (buf, jnp.zeros((0,), jnp.float32)), jnp.zeros_like(buf)
     packed, scales = compress_onebit(buf, cfg.block_size, cfg.use_kernel)
-    new_err = buf - decompress_onebit(packed, scales, cfg.block_size,
-                                      cfg.use_kernel)
-    return (packed, scales), new_err
+    return (packed, scales), onebit_residual(buf, scales, cfg.block_size)
 
 
 def ef_decompress(payload: Tuple[jax.Array, jax.Array],

@@ -1,3 +1,24 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's compute hot spots: ``onebit`` (fused
+error-feedback 1-bit compression), ``fused_adam`` (the warmup-stage Adam
+update) and ``flash_attn`` (attention forward).
+
+Each kernel compiles to Mosaic when lowered for a TPU and runs through the
+Pallas interpreter when lowered for the CPU, where the tests check it
+against its ``ref.py``.  :func:`on_platform` makes that choice at lowering
+time; nothing here asks for a backend when it is imported.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+
+
+def on_platform(call, *args):
+    """``call(interpret, *args)``: compiled when lowered for a TPU, through
+    the Pallas interpreter when lowered for the CPU.  Lowering for any
+    other platform is an error, and a TPU program never holds the
+    interpreter."""
+    return jax.lax.platform_dependent(
+        *args, cpu=functools.partial(call, True),
+        tpu=functools.partial(call, False))

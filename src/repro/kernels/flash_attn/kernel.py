@@ -11,12 +11,15 @@ TPU adaptation (vs the CUDA flash-attention):
     (256, 128) operands feed the 128x128 MXU with full lanes; the
     (BQ, BK) f32 score tile is 256 KiB of VMEM;
   * the kv loop is a ``lax.fori_loop`` inside the kernel body (sequential
-    per grid step, pipelined across grid steps by the Pallas runtime);
+    per grid step, pipelined across grid steps by the Pallas runtime); each
+    iteration slices its (BK, D) block from the K/V refs, and the running
+    max and normalizer are (BQ, 1) columns;
   * causal masking prunes whole kv blocks past the diagonal by clamping
     the loop bound (no wasted MXU work right of the diagonal);
   * optional sliding window adds the left bound.
 
-Validated in interpret mode against ``ref.sdpa``.
+Compiled when lowered for a TPU; tested in interpret mode on the CPU
+against ``ref.sdpa`` (``repro.kernels.on_platform``).
 """
 from __future__ import annotations
 
@@ -26,6 +29,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import on_platform
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
@@ -51,11 +56,11 @@ def _flash_kernel(causal: bool, window: Optional[int], bk: int, s_kv: int,
 
     def body(j, carry):
         m_prev, l_prev, o_prev = carry
-        k = jax.lax.dynamic_slice(k_ref[0], (j * bk, 0), (bk, d)
-                                  ).astype(jnp.float32)
-        v = jax.lax.dynamic_slice(v_ref[0], (j * bk, 0), (bk, d)
-                                  ).astype(jnp.float32)
-        s = q @ k.T                                      # (BQ, BK)
+        kv = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k = k_ref[0, kv, :].astype(jnp.float32)
+        v = v_ref[0, kv, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         mask = jnp.ones((bq, bk), bool)
@@ -64,33 +69,28 @@ def _flash_kernel(causal: bool, window: Optional[int], bk: int, s_kv: int,
         if window is not None:
             mask = mask & (cols > rows - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.max(s, axis=1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1)
-        o_new = o_prev * corr[:, None] + p @ v
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        o_new = o_prev * corr + jnp.dot(p, v,
+                                        preferred_element_type=jnp.float32)
         return m_new, l_new, o_new
 
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
+    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
     o0 = jnp.zeros((bq, d), jnp.float32)
     m, l, o = jax.lax.fori_loop(k0, n_kv, body, (m0, l0, o0))
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq",
                                              "bk", "interpret"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True, window: Optional[int] = None,
-                    bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True) -> jax.Array:
-    """q/k/v: (B, H, S, D) -> (B, H, S, D). S % bq == S % bk == 0."""
+def _flash_call(interpret: bool, q: jax.Array, k: jax.Array, v: jax.Array,
+                causal: bool, window: Optional[int], bq: int, bk: int):
     b, h, s, d = q.shape
-    assert s % bq == 0 and s % bk == 0, (s, bq, bk)
-    qf = q.reshape(b * h, s, d)
-    kf = k.reshape(b * h, s, d)
-    vf = v.reshape(b * h, s, d)
+    qf, kf, vf = (a.reshape(b * h, s, d) for a in (q, k, v))
     out = pl.pallas_call(
         functools.partial(_flash_kernel, causal, window, bk, s),
         grid=(b * h, s // bq),
@@ -104,3 +104,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s, d)
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = True, window: Optional[int] = None,
+                    bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK) -> jax.Array:
+    """q/k/v: (B, H, S, D) -> (B, H, S, D). S % bq == S % bk == 0."""
+    s = q.shape[2]
+    assert s % bq == 0 and s % bk == 0, (s, bq, bk)
+    return on_platform(
+        functools.partial(_flash_call, causal=causal, window=window, bq=bq,
+                          bk=bk), q, k, v)

@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the flash-attention kernel (interpret on CPU)."""
+"""Public wrapper for the flash-attention kernel."""
 from __future__ import annotations
 
 from typing import Optional
@@ -6,8 +6,6 @@ from typing import Optional
 import jax
 
 from repro.kernels.flash_attn import kernel as K
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -23,4 +21,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     bq = min(bq or K.DEFAULT_BQ, s)
     bk = min(bk or K.DEFAULT_BK, s)
     return K.flash_attention(q, k, v, causal=causal, window=window,
-                             bq=bq, bk=bk, interpret=_INTERPRET)
+                             bq=bq, bk=bk)

@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the fused Adam kernel with automatic padding."""
+"""Public wrapper for the fused Adam kernel with automatic padding."""
 from __future__ import annotations
 
 from typing import Tuple
@@ -8,22 +8,17 @@ import jax.numpy as jnp
 
 from repro.kernels.fused_adam import kernel as K
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 def adam_step(x: jax.Array, m: jax.Array, v: jax.Array, g: jax.Array,
               lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
-              weight_decay: float = 0.0, tile: int = K.DEFAULT_TILE
+              weight_decay: float = 0.0
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused BertAdam step on flat f32 vectors; pads to the tile size."""
+    """Fused BertAdam step on flat f32 vectors; pads to whole lane rows."""
     d = x.shape[0]
-    pad = (-d) % tile
+    pad = (-d) % K.LANES
     if pad:
-        z = jnp.zeros((pad,), jnp.float32)
-        x, m, v, g = (jnp.concatenate([a, z]) for a in (x, m, v, g))
-    nx, nm, nv = K.adam_step(x, m, v, g, jnp.asarray(lr, jnp.float32),
-                             b1, b2, eps, weight_decay, tile,
-                             interpret=_INTERPRET)
+        x, m, v, g = (jnp.pad(a, (0, pad)) for a in (x, m, v, g))
+    nx, nm, nv = K.adam_step(x, m, v, g, lr, b1, b2, eps, weight_decay)
     if pad:
         nx, nm, nv = nx[:d], nm[:d], nv[:d]
     return nx, nm, nv

@@ -12,14 +12,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-_POW2 = 2 ** jnp.arange(8, dtype=jnp.uint8)
-
-
 def compress(x: jax.Array, block_size: int) -> Tuple[jax.Array, jax.Array]:
     """(d,) f32 -> ((d/8,) u8, (d/block,) f32)."""
     assert x.ndim == 1 and x.shape[0] % block_size == 0
     bits = (x >= 0).astype(jnp.uint8).reshape(-1, 8)
-    packed = jnp.sum(bits * _POW2, axis=1, dtype=jnp.uint8)
+    pow2 = 2 ** jnp.arange(8, dtype=jnp.uint8)
+    packed = jnp.sum(bits * pow2, axis=1, dtype=jnp.uint8)
     scales = jnp.mean(jnp.abs(x.reshape(-1, block_size)), axis=1)
     return packed, scales
 

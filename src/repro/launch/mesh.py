@@ -10,18 +10,21 @@ touches jax device state (device count is locked at first use).
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Small helper for tests/examples (Auto axis types where supported)."""
-    return compat.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types (sharding propagated by the
+    compiler inside the step's ``shard_map``)."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 # hardware constants: single-sourced from repro.perf.device (the TPU v5e

@@ -64,10 +64,13 @@ import json
 import math
 import os
 import time
+from pathlib import Path
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import (SHAPES, get_config, get_optim_recipe, list_archs,
                            list_optim_recipes)
@@ -79,10 +82,24 @@ from repro.obs import (AUDIT_MODES, FiniteGuard, HealthMonitor,
                        MEMORY_MODES, MetricBuffer, Tracer, as_sink,
                        make_audit_probe, set_tracing)
 from repro.optim import WarmupSwitch, list_compressors, list_optimizers
+from repro.perf import resolve_device
 from repro.state import load_train_state, save_train_state
 from repro.train.step import (TrainStepConfig, _flat_dim, init_train_state,
                               make_train_step, mesh_axes, pod_split,
-                              state_layout_ctx)
+                              state_layout_ctx, train_state_specs)
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself), else the
+    fixed ``<repo>/.jax_cache`` — a fixed path, so a later run finds it."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def bwd_ready_fn(cfg, batch: int, seq: int, device, tp: int = 1):
@@ -537,13 +554,18 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
         compressor: Optional[str] = None, topology: Optional[str] = None,
         cluster: str = "ethernet-10g", pipeline=None, kernels=None,
         overlap_bwd: str = "off",
-        device: str = "tpu-v5e", telemetry: Optional[str] = None,
+        device: Optional[str] = None, telemetry: Optional[str] = None,
         drift_probe: bool = False, profile: Optional[str] = None,
         profile_steps: int = 4, bench: Optional[str] = None,
         audit: str = "off", audit_every: int = 10,
-        memory: str = "off"):
+        memory: str = "off", programs: Optional[dict] = None):
+    """Train ``arch`` for ``steps`` steps; returns ``(params, opt,
+    history)``.  ``programs``, when given, is filled with the jitted step
+    programs the run used, keyed ``(stage, sync)`` (their ``build`` and
+    ``lower`` give the compiled HLO)."""
     assert audit in AUDIT_MODES, audit
     assert memory in MEMORY_MODES, memory
+    device = resolve_device(device)
     cfg = get_config(arch)
     axes = ("data", "model")[:len(mesh_shape)] if len(mesh_shape) <= 2 else \
         ("pod", "data", "model")
@@ -599,10 +621,25 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
     layout = "local" if optim.may_skip_sync else "replicated"
     base_tsc = dataclasses.replace(base_tsc, layout=layout)
 
+    def place(tree, specs, put=jax.device_put):
+        """Lay a state tree out as the step expects it, so it is spread
+        over the mesh before the first step and not left on device 0."""
+        return jax.tree.map(
+            lambda p, a: put(a, NamedSharding(mesh, p)),
+            specs, tree, is_leaf=lambda x: isinstance(x, P))
+
+    param_specs = T.param_specs(cfg, base_tsc.model_axis, tp)
+    opt_specs = train_state_specs(mesh, base_tsc.model_axis, layout, optim)
     key = jax.random.PRNGKey(seed)
     params = T.init_params(cfg, key, tp=tp)
-    opt = init_train_state(cfg, mesh, block=block_size, layout=layout,
-                           topology=topology, optimizer=optim)
+    # the zero optimizer state is made in place on every device: built
+    # whole on device 0 first, its per-rank slots (dp copies of the flat
+    # model) overfill that device at bert-large width and dp 4
+    opt = place(init_train_state(cfg, mesh, block=block_size, layout=layout,
+                                 topology=topology, optimizer=optim,
+                                 abstract=True), opt_specs,
+                lambda sd, sharding: jnp.zeros(sd.shape, sd.dtype,
+                                               device=sharding))
     # the slot-registry context every checkpoint conversion derives from:
     # EF slots are SAVED in the canonical (serial) global-element keying
     # and scattered into this run's bucket partition on load, so
@@ -620,16 +657,18 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
             resume, params, opt, slots=slots, ctx=state_ctx,
             n_buckets=n_buckets, block=spec.block_size)
         print(f"resumed from {resume} at step {start_step}")
+    params, opt = place(params, param_specs), place(opt, opt_specs)
 
-    steps_fns = {}
+    # each step donates params and optimizer state: without it the
+    # program holds both twice, as inputs and as outputs
+    steps_fns = {} if programs is None else programs
 
     def get_step(stage: str, sync: bool = True):
         key = (stage, sync)
         if key not in steps_fns:
             steps_fns[key] = make_train_step(
                 cfg, mesh,
-                dataclasses.replace(base_tsc, stage=stage, sync=sync),
-                donate=False)
+                dataclasses.replace(base_tsc, stage=stage, sync=sync))
         return steps_fns[key]
 
     # manual T_w when given (and not auto); otherwise the paper's Sec. 7.1
@@ -806,15 +845,27 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
                         audit_probe = make_audit_probe(
                             cfg, mesh, dataclasses.replace(
                                 base_tsc, stage="compressed"))
-                        shadow_v = opt["v"]   # seed the shadow EMA
+                        # seed the shadow EMA with its own copy: the
+                        # step donates opt["v"]
+                        shadow_v = jnp.copy(opt["v"])
                     # probe BEFORE the step: audits exactly the
                     # (params, state, batch) this step consumes
                     shadow_v, astats = audit_probe(params, opt,
                                                    shadow_v, batch_data)
                     abuf.push(step, astats)
                 audit_idx += 1
-            params, opt, metrics = get_step(stage, sync)(params, opt,
-                                                         batch_data, lr)
+            fresh = (stage, sync) not in steps_fns
+            step_fn = get_step(stage, sync)
+            t_call = time.time()
+            params, opt, metrics = step_fn(params, opt, batch_data, lr)
+            if fresh:
+                # dispatch is asynchronous: a program's first call returns
+                # once it is traced, lowered and compiled
+                dt = time.time() - t_call
+                name = f"{stage}{'' if sync else '_local'}"
+                print(f"compiled the {name} step in {dt:.1f}s")
+                sink.emit("span", name=f"compile.{name}", stream="host",
+                          t_start=t_call, dur=dt, n=1, step=step)
             # park the device metrics — async dispatch, no host sync;
             # only consumers that need host floats THIS step fetch them
             # (one batched transfer), everything else waits for a drain
@@ -870,39 +921,28 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
         drain()
         mem_extra = None
         if memory_on:
-            try:  # a failed attribution must not lose the run
-                from repro.obs.mem import mem_metrics
-                biggest = emit_memory_attribution(
-                    steps_fns, (params, opt, batch_data, lr), sink,
-                    mem_ledger, telemetry_dir=telemetry)
-                mem_extra = mem_metrics(
-                    mem_ledger, compiled=biggest,
-                    live_peak=mem_sampler.peak_bytes
-                    if mem_sampler else None)
-            except Exception as e:
-                sink.emit("warning", what="memory.attribution",
-                          detail=str(e)[:400])
-                print(f"[warn] memory attribution failed: {e}")
+            from repro.obs.mem import mem_metrics
+            biggest = emit_memory_attribution(
+                steps_fns, (params, opt, batch_data, lr), sink,
+                mem_ledger, telemetry_dir=telemetry)
+            mem_extra = mem_metrics(
+                mem_ledger, compiled=biggest,
+                live_peak=mem_sampler.peak_bytes if mem_sampler else None)
         if prof_span is not None:
             # the drain above materialised the window's metrics — a real
             # host sync — so the span's wall clock is honest
             prof_span.__exit__(None, None, None)
             prof_span = None
             jax.profiler.stop_trace()
-            try:
-                emit_profile_ledger(
-                    profile, steps_fns, (params, opt, batch_data, lr),
-                    sink, optim, cfg, mesh, topology, n_buckets,
-                    spec.block_size, cluster, device,
-                    n_steps=steps - prof_start, stage=stage,
-                    bench=bench, arch=arch, mesh_shape=mesh_shape,
-                    use_kernel=bool(use_kernel),
-                    extra_metrics=mem_extra,
-                    overlap_bwd=bool(overlap_on), batch=batch, seq=seq)
-            except Exception as e:   # a failed fold must not lose the run
-                sink.emit("warning", what="profile.fold",
-                          detail=str(e)[:400])
-                print(f"[warn] profile fold failed: {e}")
+            emit_profile_ledger(
+                profile, steps_fns, (params, opt, batch_data, lr),
+                sink, optim, cfg, mesh, topology, n_buckets,
+                spec.block_size, cluster, device,
+                n_steps=steps - prof_start, stage=stage,
+                bench=bench, arch=arch, mesh_shape=mesh_shape,
+                use_kernel=bool(use_kernel),
+                extra_metrics=mem_extra,
+                overlap_bwd=bool(overlap_on), batch=batch, seq=seq)
         if ckpt:
             with tracer.span("checkpoint.save", step=steps):
                 save_train_state(ckpt, params, opt, steps, slots=slots,
@@ -982,10 +1022,12 @@ def main(argv=None):
                          "pass; needs --pipeline > 1, bitwise identical "
                          "losses; auto = the four-stream cost model "
                          "decides per --cluster/--device")
-    ap.add_argument("--device", default="tpu-v5e",
+    ap.add_argument("--device", default=None,
                     help="device preset for the compute-stream pricing "
                          "(repro.perf.list_devices()), used by "
-                         "--topology/--pipeline/--kernels auto")
+                         "--topology/--pipeline/--kernels auto; on a TPU "
+                         "it follows the chip found (naming another chip "
+                         "is an error), elsewhere it defaults to tpu-v5e")
     ap.add_argument("--block-size", type=int, default=4096)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default=None)
@@ -1034,6 +1076,7 @@ def main(argv=None):
                     help="perf-ledger name for --profile "
                          "(BENCH_<NAME>.json; default: train)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     mesh_shape = tuple(int(x) for x in args.mesh.split("x"))
     run(args.arch, args.steps, args.batch, args.seq, mesh_shape,
         base_lr=args.lr, lr_warmup=args.lr_warmup,

@@ -88,7 +88,6 @@ def make_audit_probe(cfg, mesh, tsc):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.models import transformer as T
     from repro.optim.base import AUDIT_SCALAR_KEYS, AUDIT_SEG_KEYS
     from repro.state import StateTree
@@ -137,10 +136,10 @@ def make_audit_probe(cfg, mesh, tsc):
             bspec = _select(batch_specs(cfg, "train", dp_axes),
                             batch_tree)
             sspec = {k: P() for k in stat_keys}
-            mapped = shard_map(probe, mesh=mesh,
-                               in_specs=(pspecs, osp, sv_spec, bspec),
-                               out_specs=(sv_spec, sspec),
-                               check_vma=False)
+            mapped = jax.shard_map(probe, mesh=mesh,
+                                   in_specs=(pspecs, osp, sv_spec, bspec),
+                                   out_specs=(sv_spec, sspec),
+                                   check_vma=False)
             _cache[key] = jax.jit(mapped)
         return _cache[key]
 

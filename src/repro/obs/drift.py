@@ -215,7 +215,7 @@ def probe_plan(plan, mesh, iters: int = 4,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from repro.plan.executor import chunk_all_to_all
     from repro.plan.ir import (AllGather, AllReduce, AllToAll, Broadcast,
                                ReduceScatter)
 
@@ -231,9 +231,7 @@ def probe_plan(plan, mesh, iters: int = 4,
             for p in (tuple(jnp.zeros(w.shape, dtype=w.dtype)
                             for w in o.payload)):
                 if isinstance(o, AllToAll):
-                    r = jax.lax.all_to_all(p.reshape(o.n, -1), o.axes,
-                                           split_axis=0, concat_axis=0,
-                                           tiled=False)
+                    r = chunk_all_to_all(p, o.n, o.axes)
                 elif isinstance(o, AllGather):
                     r = jax.lax.all_gather(p, o.axes, tiled=o.tiled)
                 elif isinstance(o, AllReduce):
@@ -254,8 +252,8 @@ def probe_plan(plan, mesh, iters: int = 4,
             return jax.lax.pmean(jnp.stack(outs).sum(),
                                  tuple(mesh.axis_names))
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(),
-                               out_specs=P(), check_vma=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(),
+                                   out_specs=P(), check_vma=False))
         jax.block_until_ready(fn())          # compile outside the clock
         for _ in range(max(repeats, 1)):
             best = float("inf")

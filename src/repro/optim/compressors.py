@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.compression import (CompressionConfig, DEFAULT_BLOCK,
-                                    compress_onebit, decompress_onebit)
+                                    compress_onebit, decompress_onebit,
+                                    onebit_residual)
 from repro.perf.kernel_cost import (ComputeSpec, ZERO_COMPUTE,
                                     ef_combine_cost, elementwise_pass)
 from repro.plan.ir import WireSpec, log2ceil
@@ -128,7 +129,9 @@ class OneBitCompressor(Compressor):
             pk, sc, new_err = _kops.ef_compress_fused(
                 x + 0.0, err, block_size=self.block_size)
             return (pk, sc), new_err
-        return super().ef_compress(x, err)
+        buf = x + err
+        payload = self.compress(buf)
+        return payload, onebit_residual(buf, payload[1], self.block_size)
 
     def decompress(self, payload):
         packed, scales = payload

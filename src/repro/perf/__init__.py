@@ -15,7 +15,8 @@ kernel_sweep.py`` calibrates HBM bandwidth + kernel launch overhead
 from timed kernels, mirroring ``comm_sweep.py`` for links.
 """
 from repro.perf.device import (DEVICES, DeviceSpec, as_device, get_device,
-                               host_memory_bytes, list_devices)
+                               host_memory_bytes, list_devices,
+                               preset_for_kind, resolve_device)
 from repro.perf.kernel_cost import (ComputeSpec, ZERO_COMPUTE,
                                     adam_update_cost, combine_cost,
                                     ef_combine_cost, elementwise_pass)
@@ -24,4 +25,5 @@ __all__ = [
     "DEVICES", "DeviceSpec", "ComputeSpec", "ZERO_COMPUTE",
     "adam_update_cost", "as_device", "combine_cost", "ef_combine_cost",
     "elementwise_pass", "get_device", "host_memory_bytes", "list_devices",
+    "preset_for_kind", "resolve_device",
 ]

@@ -120,6 +120,42 @@ DEVICES: Dict[str, DeviceSpec] = {
 }
 
 
+# ``jax.Device.device_kind`` of each TPU generation -> its preset.  A TPU
+# whose kind is not here is an error, never priced as some other chip.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "tpu-v4",
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v5": "tpu-v5p",
+}
+
+
+def preset_for_kind(kind: str) -> str:
+    """The preset of a TPU ``device_kind``; unknown kinds raise."""
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"no device preset for TPU device_kind {kind!r}; "
+                       f"known kinds: {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]
+
+
+def resolve_device(requested: Optional[str] = None, device=None) -> str:
+    """The preset that prices this process's accelerator.
+
+    On a TPU (``device``, default ``jax.devices()[0]``) the preset follows
+    its ``device_kind``, and a ``requested`` preset naming another chip is
+    an error.  Elsewhere (the CPU runs that test the pricing) the
+    ``requested`` preset is used as given, ``tpu-v5e`` when none is."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return requested or "tpu-v5e"
+    found = preset_for_kind(device.device_kind)
+    if requested is not None and requested != found:
+        raise ValueError(f"--device {requested!r} does not match the chip "
+                         f"found ({device.device_kind!r} -> {found!r})")
+    return found
+
+
 def host_memory_bytes() -> Optional[int]:
     """Total installed host RAM in bytes (psutil), or None."""
     try:

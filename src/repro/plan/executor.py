@@ -34,6 +34,7 @@ tests/test_obs.py) — and a shared nullcontext when disabled.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -66,12 +67,25 @@ def _compress(op: CollectiveOp, comp, value: jax.Array, errs: Errs
     return payload, errs
 
 
+def chunk_all_to_all(p: jax.Array, n: int, axes) -> jax.Array:
+    """Exchange the ``n`` equal chunks of a flat payload leaf over
+    ``axes``; returns the ``(n, chunk)`` received chunks.
+
+    Each chunk travels as rows of up to 128 lanes, ``(n, chunk/L, L)``:
+    the same bytes, but the TPU compiler takes minutes over a 2-D
+    ``uint8`` all_to_all at BERT-Large length and under a second over
+    this one."""
+    chunk = p.shape[0] // n
+    lanes = math.gcd(chunk, 128)
+    recv = jax.lax.all_to_all(p.reshape(n, chunk // lanes, lanes), axes,
+                              split_axis=0, concat_axis=0, tiled=False)
+    return recv.reshape(n, chunk)
+
+
 def _exec_all_to_all(op: AllToAll, comp, value, errs):
     payload, errs = _compress(op, comp, value, errs)
     if op.axes:
-        recv = [jax.lax.all_to_all(p.reshape(op.n, -1), op.axes,
-                                   split_axis=0, concat_axis=0, tiled=False)
-                for p in payload]
+        recv = [chunk_all_to_all(p, op.n, op.axes) for p in payload]
         vals = jax.vmap(lambda *leaves: comp.decompress(tuple(leaves)))(*recv)
         if op.combine == "mean":
             value = jnp.mean(vals, axis=0)

@@ -65,7 +65,6 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, InputShape
 from repro.core import onebit_adam as OB
 from repro.core.compression import padded_length
@@ -580,7 +579,7 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
             bspec = _select(batch_specs(cfg, "train", dp_axes), batch_tree)
             mspec = {k: P() for k in
                      ["loss", "aux", "acc", "total", *STAT_KEYS]}
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 step, mesh=mesh,
                 in_specs=(pspecs, osp, bspec, P()),
                 out_specs=(pspecs, osp, mspec),
@@ -636,9 +635,10 @@ def make_serve_step(cfg: ArchConfig, mesh: Mesh, shape: InputShape,
             if key not in _cache:
                 bspec = _select(batch_specs(cfg, shape.kind, dp_axes),
                                 batch_tree)
-                mapped = shard_map(pre, mesh=mesh, in_specs=(pspecs, bspec),
-                                   out_specs=P(dp_axes, model_axis),
-                                   check_vma=False)
+                mapped = jax.shard_map(pre, mesh=mesh,
+                                       in_specs=(pspecs, bspec),
+                                       out_specs=P(dp_axes, model_axis),
+                                       check_vma=False)
                 _cache[key] = jax.jit(mapped)
             return _cache[key]
 
@@ -674,10 +674,10 @@ def make_serve_step(cfg: ArchConfig, mesh: Mesh, shape: InputShape,
                     is_leaf=lambda s: isinstance(s, P))
             logits_spec = (P(None, model_axis) if seq_sharded
                            else P(dp_axes, model_axis))
-            mapped = shard_map(dec, mesh=mesh,
-                               in_specs=(pspecs, bspec, cspecs, P()),
-                               out_specs=(logits_spec, cspecs),
-                               check_vma=False)
+            mapped = jax.shard_map(dec, mesh=mesh,
+                                   in_specs=(pspecs, bspec, cspecs, P()),
+                                   out_specs=(logits_spec, cspecs),
+                                   check_vma=False)
             _cache[key] = jax.jit(mapped, donate_argnums=(2,))
         return _cache[key]
 

@@ -3,9 +3,14 @@
 Sweeps shapes and value scales with hypothesis, as required for every
 Pallas kernel in the repo.
 """
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -196,3 +201,47 @@ class TestFlashAttentionKernel:
         np.testing.assert_allclose(np.asarray(outs["pallas"]),
                                    np.asarray(outs["full"]),
                                    rtol=1e-4, atol=1e-4)
+
+
+class TestPlatformChoice:
+    """The interpreter is picked when a kernel is lowered for the CPU and
+    only then: a TPU program holds the compiled Mosaic kernel, and no
+    other platform gets a silent fallback."""
+
+    CASES = {
+        "onebit": lambda x: ob_ops.ef_compress_fused(x, x, 1024),
+        "onebit_decompress": lambda x: ob_ops.decompress(
+            x[:512].astype(jnp.uint8), x[:4], 1024),
+        "fused_adam": lambda x: fa_ops.adam_step(x, x, x, x, 1e-3),
+    }
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_interpreted_only_on_cpu(self, name):
+        traced = jax.jit(self.CASES[name]).trace(rand(4096))
+        cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+        tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" not in cpu
+        assert "tpu_custom_call" in tpu
+        with pytest.raises(NotImplementedError):
+            traced.lower(lowering_platforms=("cuda",))
+
+    def test_flash_attention_interpreted_only_on_cpu(self):
+        from repro.kernels.flash_attn import ops as fl_ops
+        q = jnp.zeros((1, 2, 128, 64), jnp.float32)
+        traced = jax.jit(lambda a: fl_ops.flash_attention(a, a, a)).trace(q)
+        assert "tpu_custom_call" not in traced.lower(
+            lowering_platforms=("cpu",)).as_text()
+        assert "tpu_custom_call" in traced.lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def test_import_starts_no_backend(self):
+        code = ("import repro.kernels.onebit, repro.kernels.fused_adam, "
+                "repro.kernels.flash_attn, repro.core.compression, "
+                "repro.launch.train; "
+                "from jax._src import xla_bridge; "
+                "assert not xla_bridge._backends, xla_bridge._backends")
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, PYTHONPATH=src)
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr

@@ -400,3 +400,37 @@ class TestPipelinedKernelParity:
             np.testing.assert_allclose(np.asarray(errs_j[slot]),
                                        np.asarray(errs_k[slot]),
                                        rtol=1e-5, atol=1e-6)
+
+
+class TestDeviceKinds:
+    """On a TPU the pricing preset follows ``device_kind``; an unknown
+    kind or a preset naming another chip is an error, not a default."""
+
+    class _Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    def test_v5e_kind_maps_to_its_preset(self):
+        from repro.perf import resolve_device
+        dev = self._Dev("tpu", "TPU v5 lite")
+        assert resolve_device(None, dev) == "tpu-v5e"
+        assert resolve_device("tpu-v5e", dev) == "tpu-v5e"
+
+    def test_unknown_kind_raises(self):
+        from repro.perf import preset_for_kind, resolve_device
+        with pytest.raises(KeyError):
+            preset_for_kind("TPU v99")
+        with pytest.raises(KeyError):
+            resolve_device(None, self._Dev("tpu", "TPU v99"))
+
+    def test_preset_naming_another_chip_raises(self):
+        from repro.perf import resolve_device
+        with pytest.raises(ValueError):
+            resolve_device("tpu-v4", self._Dev("tpu", "TPU v5 lite"))
+
+    def test_cpu_keeps_the_requested_preset(self):
+        from repro.perf import resolve_device
+        cpu = self._Dev("cpu", "cpu")
+        assert resolve_device(None, cpu) == "tpu-v5e"
+        assert resolve_device("cpu-host", cpu) == "cpu-host"
+        assert resolve_device("tpu-v4", cpu) == "tpu-v4"

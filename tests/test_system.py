@@ -221,6 +221,40 @@ class TestTrainDriverCLI:
         assert hist[6]["stage"] == "compressed"
         assert np.isfinite(hist[-1]["loss"])
 
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_compile_cache_directory(self, tmp_path, from_env):
+        """``JAX_COMPILATION_CACHE_DIR`` when set, and compiled programs
+        land there; otherwise the fixed ``<repo>/.jax_cache``."""
+        import subprocess
+        import sys
+        code = ("import jax, jax.numpy as jnp\n"
+                "from repro.launch.train import REPO_ROOT, use_compile_cache\n"
+                "path = use_compile_cache()\n"
+                "assert jax.config.jax_compilation_cache_dir == path\n"
+                "if jax.config.jax_compilation_cache_dir != "
+                "str(REPO_ROOT / '.jax_cache'):\n"
+                "    jax.jit(lambda x: jnp.sin(x) @ x)(jnp.ones((8, 8)))"
+                ".block_until_ready()\n"
+                "print(path)\n")
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(PYTHONPATH=src,
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                   JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+        if from_env:
+            env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr
+        path = r.stdout.strip().splitlines()[-1]
+        if from_env:
+            assert path == str(tmp_path)
+            assert any(tmp_path.iterdir())
+        else:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert path == os.path.join(repo, ".jax_cache")
+
 
 class TestGradAccumulation:
     def test_accum_matches_single_batch(self):
