@@ -41,6 +41,7 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as obs
 from repro.plan import executor as _exec
 from repro.plan import schedules as _sched
 
@@ -117,10 +118,11 @@ def allreduce_mean(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
     axes = tuple(axis_names)
     if not axes:
         return x
-    if x.ndim != 1:
-        return jax.lax.pmean(x, axes)
-    plan = _sched.allreduce_schedule(x.shape[0], axis_size(axes), axes)
-    out, _ = _exec.execute_plan(plan, None, x)
+    with obs.layer_scope("exchange", "allreduce"):
+        if x.ndim != 1:
+            return jax.lax.pmean(x, axes)
+        plan = _sched.allreduce_schedule(x.shape[0], axis_size(axes), axes)
+        out, _ = _exec.execute_plan(plan, None, x)
     return out
 
 
@@ -212,19 +214,20 @@ def compressed_exchange(
     comp = _as_compressor(cfg)
     axes_in = tuple(dp_axes)
     axes_out = tuple(pod_axes)
-    n_in = axis_size(axes_in)
     d = flat_dim(x)
-    if not axes_out:
-        assert d % n_in == 0, (d, n_in)
-        plan = _sched.flat_schedule(comp, d, n_in, axes_in)
-        return _execute(plan, comp, x, errs, n_buckets, n_in)
-    outer_ef = _sched.needs_outer_ef(comp)
-    assert not outer_ef or ("outer" in errs and "outer_ag" in errs), \
-        ("hierarchical topology needs a dense (or lossless) compressor, "
-         "or the outer/outer_ag EF slots: un-compensated cross-pod legs "
-         f"would permanently drop the sparse residual of "
-         f"{type(comp).__name__}")
-    n_out = axis_size(axes_out)
-    plan = _sched.hier_schedule(comp, d, n_in, n_out, axes_in, axes_out,
-                                outer_ef=outer_ef)
-    return _execute(plan, comp, x, errs, n_buckets, n_in * n_out)
+    with obs.layer_scope("exchange", comp.name):
+        n_in = axis_size(axes_in)
+        if not axes_out:
+            assert d % n_in == 0, (d, n_in)
+            plan = _sched.flat_schedule(comp, d, n_in, axes_in)
+            return _execute(plan, comp, x, errs, n_buckets, n_in)
+        outer_ef = _sched.needs_outer_ef(comp)
+        assert not outer_ef or ("outer" in errs and "outer_ag" in errs), \
+            ("hierarchical topology needs a dense (or lossless) "
+             "compressor, or the outer/outer_ag EF slots: un-compensated "
+             "cross-pod legs would permanently drop the sparse residual "
+             f"of {type(comp).__name__}")
+        n_out = axis_size(axes_out)
+        plan = _sched.hier_schedule(comp, d, n_in, n_out, axes_in,
+                                    axes_out, outer_ef=outer_ef)
+        return _execute(plan, comp, x, errs, n_buckets, n_in * n_out)

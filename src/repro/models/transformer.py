@@ -28,6 +28,7 @@ from repro.models import ssm as S
 from repro.models.common import (ParallelCtx, dense, f_reduce, g_copy,
                                  rep_param, rms_norm, sp_gather, sp_scatter,
                                  sp_slice, tp_rank)
+from repro.obs import trace as obs
 
 Params = Dict[str, Any]
 
@@ -97,34 +98,38 @@ def _layer_fwd(p: Params, x: jax.Array, cfg: ArchConfig, ctx: ParallelCtx,
     norms/residual math runs on 1/tp of the tokens.
     """
     sp = ctx.sp and ctx.tp_axis is not None
-    h = rms_norm(x, rep_param(p["norm1"], ctx), cfg.norm_eps)
-    if sp:
-        h_in = sp_gather(h, ctx)
-        fwd = (A.attn_forward(p["mixer"], h_in, cfg, ctx, outer="none")
-               if mixer == "attn" else
-               S.ssm_forward(p["mixer"], h_in, cfg, ctx, outer="none"))
-        x = x + sp_scatter(fwd, ctx)
-    else:
-        if mixer == "attn":
+    with obs.layer_scope("model", "norm"):
+        h = rms_norm(x, rep_param(p["norm1"], ctx), cfg.norm_eps)
+    with obs.layer_scope("model", "attention" if mixer == "attn" else "ssm"):
+        if sp:
+            h_in = sp_gather(h, ctx)
+            fwd = (A.attn_forward(p["mixer"], h_in, cfg, ctx, outer="none")
+                   if mixer == "attn" else
+                   S.ssm_forward(p["mixer"], h_in, cfg, ctx, outer="none"))
+            x = x + sp_scatter(fwd, ctx)
+        elif mixer == "attn":
             x = x + A.attn_forward(p["mixer"], h, cfg, ctx)
         else:
             x = x + S.ssm_forward(p["mixer"], h, cfg, ctx)
     aux = jnp.zeros((), jnp.float32)
     if ffn is not None:
-        h = rms_norm(x, rep_param(p["norm2"], ctx), cfg.norm_eps)
-        if sp:
-            h_in = sp_gather(h, ctx)
-            if ffn == "moe":
-                y, aux = M.moe_forward(p["ffn"], h_in, cfg, ctx,
-                                       outer="none", x_shard=h)
+        with obs.layer_scope("model", "norm"):
+            h = rms_norm(x, rep_param(p["norm2"], ctx), cfg.norm_eps)
+        with obs.layer_scope("model", "moe" if ffn == "moe" else "mlp"):
+            if sp:
+                h_in = sp_gather(h, ctx)
+                if ffn == "moe":
+                    y, aux = M.moe_forward(p["ffn"], h_in, cfg, ctx,
+                                           outer="none", x_shard=h)
+                else:
+                    y = M.mlp_forward(p["ffn"], h_in, cfg, ctx,
+                                      outer="none")
+                y = sp_scatter(y, ctx)
+            elif ffn == "moe":
+                y, aux = M.moe_forward(p["ffn"], h, cfg, ctx)
             else:
-                y = M.mlp_forward(p["ffn"], h_in, cfg, ctx, outer="none")
-            y = sp_scatter(y, ctx)
-        elif ffn == "moe":
-            y, aux = M.moe_forward(p["ffn"], h, cfg, ctx)
-        else:
-            y = M.mlp_forward(p["ffn"], h, cfg, ctx)
-        x = x + y
+                y = M.mlp_forward(p["ffn"], h, cfg, ctx)
+            x = x + y
     return x, aux
 
 
@@ -313,21 +318,23 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], cfg: ArchConfig,
     """
     dtype = jnp.dtype(cfg.compute_dtype)
     sp = ctx.sp and ctx.tp_axis is not None
-    h = _inputs_to_h0(params, batch, cfg, ctx, dtype, sp=sp)
+    with obs.layer_scope("model", "embed"):
+        h = _inputs_to_h0(params, batch, cfg, ctx, dtype, sp=sp)
     h, aux = _run_blocks(params, h, cfg, ctx)
-    h = rms_norm(h, rep_param(params["norm_f"], ctx), cfg.norm_eps)
-    if sp:
-        # LM head stays vocab-parallel: gather the (norm'd) hiddens back to
-        # the full sequence (Megatron-SP's final gather)
-        h = sp_gather(h, ctx)
-
-    labels = batch["labels"]
-    if cfg.embed_kind == "prefix":
-        h = h[:, -labels.shape[1]:, :]      # loss over text positions only
-    mask = batch.get("loss_mask", jnp.ones(labels.shape, jnp.float32))
-    loss, acc = vocab_parallel_xent(h, params["w_out"], labels, mask, cfg,
-                                    ctx, skip_gcopy=sp)
-    total = loss + aux_weight * aux
+    with obs.layer_scope("model", "norm"):
+        h = rms_norm(h, rep_param(params["norm_f"], ctx), cfg.norm_eps)
+    with obs.layer_scope("model", "head"):
+        if sp:
+            # LM head stays vocab-parallel: gather the (norm'd) hiddens
+            # back to the full sequence (Megatron-SP's final gather)
+            h = sp_gather(h, ctx)
+        labels = batch["labels"]
+        if cfg.embed_kind == "prefix":
+            h = h[:, -labels.shape[1]:, :]  # loss over text positions only
+        mask = batch.get("loss_mask", jnp.ones(labels.shape, jnp.float32))
+        loss, acc = vocab_parallel_xent(h, params["w_out"], labels, mask,
+                                        cfg, ctx, skip_gcopy=sp)
+        total = loss + aux_weight * aux
     return total, {"loss": loss, "aux": aux, "acc": acc}
 
 

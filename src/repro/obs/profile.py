@@ -48,13 +48,15 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 # the span grammar of repro.obs.trace.span_name: plan names may contain
 # "/", "(", ")", "+" (e.g. "pipe(flat/onebit)x2", "hier/onebit+outer_ef")
-# so the plan segment is a non-greedy anything-up-to the next "::".  The
+# so the plan segment is a non-greedy anything-up-to the next "::" that
+# never runs into another "obs::" scope: a grid scope nests under its
+# "obs::exchange::<compressor>" layer scope in an HLO op_name path.  The
 # canonical tier separator is "~" (JAX's name stack eats "@" and all
 # that follows before the scope reaches HLO metadata); "@" is still
 # accepted for host-span logs written before the rename.
 SCOPE_RE = re.compile(
-    r"obs::(?P<plan>.+?)::(?:b(?P<bucket>\d+)\.)?s(?P<stage>\d+)"
-    r"::(?P<kind>[A-Za-z]+)[~@](?P<tier>[a-z]+)")
+    r"obs::(?P<plan>(?:(?!obs::).)+?)::(?:b(?P<bucket>\d+)\.)?"
+    r"s(?P<stage>\d+)::(?P<kind>[A-Za-z]+)[~@](?P<tier>[a-z]+)")
 
 # XLA mnemonics of the wire legs (vs fusions/etc = compute carrying the
 # scope of the op they belong to); matches repro.obs.trace._COLLECTIVE_RE

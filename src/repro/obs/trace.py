@@ -1,7 +1,19 @@
-"""Trace spans: executor op scopes, host wall-clock spans, and the
-collective-signature helper that keeps them honest.
+"""Trace spans: layer scopes, executor op scopes, host wall-clock spans,
+and the collective-signature helper that keeps them honest.
 
-Two kinds of span, because JAX separates trace time from run time:
+Three kinds of span, because JAX separates trace time from run time:
+
+  * **Layer scopes** (:func:`layer_scope`) — ``obs::<layer>::<part>``
+    ``jax.named_scope`` annotations around each layer's work in the
+    train step: ``model::{embed,norm,attention,mlp,moe,ssm,head}``,
+    ``optimizer::{flatten,momentum,update,stats,unflatten}`` and
+    ``exchange::{<compressor>,allreduce,gather_replica}``.  Always on: a
+    name stack entry is HLO metadata only, so the timed and the traced
+    program are one program (tests/test_obs.py pins the compiled HLO,
+    metadata stripped, equal with and without them).  Autodiff keeps the scope in the ``op_name``
+    of the backward (``transpose(jvp(...))``) and of the recomputed
+    forward (``.../rematted_computation/...``), so a device trace tells
+    forward, backward and recomputation of each layer apart.
 
   * **Op scopes** (:func:`op_scope`) — ``jax.named_scope`` annotations
     the plan/pipelined executors wrap around every collective op at
@@ -26,8 +38,12 @@ Two kinds of span, because JAX separates trace time from run time:
 
 Span naming convention (documented in README "Observability")::
 
+    obs::<layer>::<part>                           layer scope
     obs::<plan>::s<stage>::<Kind>~<tier>          serial executor
     obs::<plan>::b<bucket>.s<stage>::<Kind>~<tier> pipelined executor
+
+A grid scope nests inside its ``obs::exchange::<compressor>`` scope:
+``.../obs::exchange::onebit/obs::flat/onebit::s1::AllToAll~intra/...``.
 
 e.g. ``obs::hier_onebit::b2.s1::AllToAll~cross`` = bucket 2's cross-pod
 all_to_all leg.  The tier separator is ``~`` because ``@`` is reserved
@@ -76,6 +92,13 @@ def span_name(plan_name: str, stage: int, kind: str, tier: str,
               bucket: Optional[int] = None) -> str:
     b = f"b{bucket}." if bucket is not None else ""
     return f"obs::{plan_name}::{b}s{stage}::{kind}~{tier}"
+
+
+def layer_scope(layer: str, part: str):
+    """The ``obs::<layer>::<part>`` named scope around one layer's work;
+    the names hold no ``/``, ``@`` or ``jvp(``."""
+    import jax
+    return jax.named_scope(f"obs::{layer}::{part}")
 
 
 def op_scope(plan_name: str, stage: int, op, bucket: Optional[int] = None):

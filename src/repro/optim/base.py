@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import comm
+from repro.obs import trace as obs
 from repro.optim.compressors import Compressor, OneBitCompressor
 from repro.state import (SlotSpec, StateLayout, StateTree, ef_errs,
                          init_rank_state)
@@ -378,33 +379,38 @@ class TwoStageOptimizer:
         the tests/test_kernels.py kernel parity tolerance).
         """
         g = comm.allreduce_mean(g_local, dp_axes)
-        count = state.count + 1
+        with obs.layer_scope("optimizer", "update"):
+            count = state.count + 1
         if self._fused_warmup_ok:
             from repro.kernels.fused_adam import ops as _fa
-            new_x, m, v = _fa.adam_step(
-                x, state.m, state.v, g, lr, b1=self.b1, b2=self.b2,
-                eps=self.eps, weight_decay=self.weight_decay)
+            with obs.layer_scope("optimizer", "update"):
+                new_x, m, v = _fa.adam_step(
+                    x, state.m, state.v, g, lr, b1=self.b1, b2=self.b2,
+                    eps=self.eps, weight_decay=self.weight_decay)
         else:
-            m = self.b1 * state.m + (1.0 - self.b1) * g
-            v = self.b2 * state.v + (1.0 - self.b2) * jnp.square(g)
-            if self.bias_correction:
-                t = count.astype(jnp.float32)
-                m_hat = m / (1.0 - self.b1 ** t)
-                v_hat = v / (1.0 - self.b2 ** t)
-            else:
-                m_hat, v_hat = m, v
-            upd = m_hat / (jnp.sqrt(v_hat) + self.eps)
-            if self.weight_decay:
-                upd = upd + self.weight_decay * x
-            seg_ids_fn = segs.ids if segs is not None else None
-            n_seg = segs.n if segs is not None else 1
-            upd = self._warmup_direction(upd, x, seg_ids_fn, n_seg,
-                                         tuple(tp_axes))
-            new_x = x - lr * upd
-        stats = self._stats(v_l1=jnp.sum(jnp.abs(v)),
-                            grad_norm=jnp.linalg.norm(g),
-                            momentum_norm=jnp.linalg.norm(m),
-                            state=state)
+            with obs.layer_scope("optimizer", "momentum"):
+                m = self.b1 * state.m + (1.0 - self.b1) * g
+            with obs.layer_scope("optimizer", "update"):
+                v = self.b2 * state.v + (1.0 - self.b2) * jnp.square(g)
+                if self.bias_correction:
+                    t = count.astype(jnp.float32)
+                    m_hat = m / (1.0 - self.b1 ** t)
+                    v_hat = v / (1.0 - self.b2 ** t)
+                else:
+                    m_hat, v_hat = m, v
+                upd = m_hat / (jnp.sqrt(v_hat) + self.eps)
+                if self.weight_decay:
+                    upd = upd + self.weight_decay * x
+                seg_ids_fn = segs.ids if segs is not None else None
+                n_seg = segs.n if segs is not None else 1
+                upd = self._warmup_direction(upd, x, seg_ids_fn, n_seg,
+                                             tuple(tp_axes))
+                new_x = x - lr * upd
+        with obs.layer_scope("optimizer", "stats"):
+            stats = self._stats(v_l1=jnp.sum(jnp.abs(v)),
+                                grad_norm=jnp.linalg.norm(g),
+                                momentum_norm=jnp.linalg.norm(m),
+                                state=state)
         return new_x, state._replace(m=m, v=v, count=count), stats
 
     # --- compression stage (ONE path, parameterised by the slots) ----------
@@ -467,27 +473,32 @@ class TwoStageOptimizer:
                        else jnp.concatenate(tuple(parts)))
             parts = None
         if parts is not None:
-            g_norm_in = jnp.concatenate(tuple(parts))
+            with obs.layer_scope("optimizer", "stats"):
+                g_norm_in = jnp.concatenate(tuple(parts))
             m_send, off = [], 0
-            for p in parts:
-                m_prev = jax.lax.slice(state.m, (off,),
-                                       (off + p.shape[0],))
-                m_send.append(self.b1 * m_prev + (1.0 - self.b1) * p)
-                off += p.shape[0]
+            with obs.layer_scope("optimizer", "momentum"):
+                for p in parts:
+                    m_prev = jax.lax.slice(state.m, (off,),
+                                           (off + p.shape[0],))
+                    m_send.append(self.b1 * m_prev + (1.0 - self.b1) * p)
+                    off += p.shape[0]
             assert off == state.m.shape[0], (off, state.m.shape)
             m_local = tuple(m_send)
         else:
             g_norm_in = g_local
-            m_local = self.b1 * state.m + (1.0 - self.b1) * g_local
+            with obs.layer_scope("optimizer", "momentum"):
+                m_local = self.b1 * state.m + (1.0 - self.b1) * g_local
         if not sync:
             x_full = self._full_params(state, x, all_axes)
-            stats = self._stats(
-                v_l1=jnp.sum(jnp.abs(state.v_shard if sharded
-                                     else state.v)),
-                grad_norm=jnp.linalg.norm(g_local),
-                momentum_norm=jnp.linalg.norm(m_local), state=state)
-            return x_full, state._replace(m=m_local,
-                                          count=state.count + 1), stats
+            with obs.layer_scope("optimizer", "stats"):
+                stats = self._stats(
+                    v_l1=jnp.sum(jnp.abs(state.v_shard if sharded
+                                         else state.v)),
+                    grad_norm=jnp.linalg.norm(g_local),
+                    momentum_norm=jnp.linalg.norm(m_local), state=state)
+            with obs.layer_scope("optimizer", "update"):
+                count = state.count + 1
+            return x_full, state._replace(m=m_local, count=count), stats
 
         # the declared ef= fields ARE the state-slot -> plan-slot map
         # (EF slots are layout-invariant, so any layout's declaration
@@ -498,59 +509,61 @@ class TwoStageOptimizer:
         m_bar, errs = comm.compressed_exchange(
             m_local, ef_errs(state, ef_slots), dp_axes, pod_axes,
             self.compressor, n_buckets=n_buckets)
-        count = state.count + 1
-        seg_ids_fn = segs.ids if segs is not None else None
-        n_seg = segs.n if segs is not None else 1
+        with obs.layer_scope("optimizer", "update"):
+            count = state.count + 1
+            seg_ids_fn = segs.ids if segs is not None else None
+            n_seg = segs.n if segs is not None else 1
 
-        if sharded:
-            n = comm.axis_size(all_axes)
-            d = m_bar.shape[0]
-            chunk = d // max(n, 1)
-            idx = (jax.lax.axis_index(all_axes) * chunk if all_axes
-                   else 0)
-            my_mbar = jax.lax.dynamic_slice(m_bar, (idx,), (chunk,))
-            my_mprev = jax.lax.dynamic_slice(state.m, (idx,), (chunk,))
-            v, v_step = self._update_v(state.v_shard, state.v_step,
-                                       my_mprev, my_mbar, count)
-            upd = my_mbar / (jnp.sqrt(v) + self.eps)
-            master = state.master_shard
-            if seg_ids_fn is not None:
-                ids_full = seg_ids_fn
-                seg_ids_fn = lambda: jax.lax.dynamic_slice(  # noqa: E731
-                    ids_full(), (idx,), (chunk,))
-            # each rank holds one chunk: segment norms need the dp psum
-            norm_axes = tuple(tp_axes) + all_axes
-        else:
-            assert x is not None, \
-                "update() needs x for the replicated/local layouts"
-            v, v_step = self._update_v(state.v, state.v_step, state.m,
-                                       m_bar, count)
-            upd = m_bar / (jnp.sqrt(v) + self.eps)
-            master = x
-            norm_axes = tuple(tp_axes)
+            if sharded:
+                n = comm.axis_size(all_axes)
+                d = m_bar.shape[0]
+                chunk = d // max(n, 1)
+                idx = (jax.lax.axis_index(all_axes) * chunk if all_axes
+                       else 0)
+                my_mbar = jax.lax.dynamic_slice(m_bar, (idx,), (chunk,))
+                my_mprev = jax.lax.dynamic_slice(state.m, (idx,), (chunk,))
+                v, v_step = self._update_v(state.v_shard, state.v_step,
+                                           my_mprev, my_mbar, count)
+                upd = my_mbar / (jnp.sqrt(v) + self.eps)
+                master = state.master_shard
+                if seg_ids_fn is not None:
+                    ids_full = seg_ids_fn
+                    seg_ids_fn = lambda: jax.lax.dynamic_slice(  # noqa: E731
+                        ids_full(), (idx,), (chunk,))
+                # each rank holds one chunk: segment norms need the dp psum
+                norm_axes = tuple(tp_axes) + all_axes
+            else:
+                assert x is not None, \
+                    "update() needs x for the replicated/local layouts"
+                v, v_step = self._update_v(state.v, state.v_step, state.m,
+                                           m_bar, count)
+                upd = m_bar / (jnp.sqrt(v) + self.eps)
+                master = x
+                norm_axes = tuple(tp_axes)
 
-        scale = self._update_scale(state.scale, master, upd, seg_ids_fn,
-                                   n_seg, norm_axes)
-        pe = self._scale_per_elem(scale, seg_ids_fn)
-        if pe is not None:
-            upd = upd * pe
-        if self.weight_decay:
-            upd = upd + self.weight_decay * master
-        new_master = master - lr * upd
+            scale = self._update_scale(state.scale, master, upd, seg_ids_fn,
+                                       n_seg, norm_axes)
+            pe = self._scale_per_elem(scale, seg_ids_fn)
+            if pe is not None:
+                upd = upd * pe
+            if self.weight_decay:
+                upd = upd + self.weight_decay * master
+            new_master = master - lr * upd
 
-        repl = {s.name: errs[s.ef] for s in ef_slots}
-        repl.update(m=m_bar, scale=scale, count=count, v_step=v_step)
+            repl = {s.name: errs[s.ef] for s in ef_slots}
+            repl.update(m=m_bar, scale=scale, count=count, v_step=v_step)
         if sharded:
             repl.update(v_shard=v, master_shard=new_master)
             x_full = self._gather_replica(new_master, all_axes)
         else:
             repl.update(v=v)
             x_full = new_master
-        stats = self._stats(v_l1=jnp.sum(jnp.abs(v)),
-                            grad_norm=jnp.linalg.norm(g_norm_in),
-                            momentum_norm=jnp.linalg.norm(m_bar),
-                            worker_err=errs["worker"],
-                            server_err=errs["server"])
+        with obs.layer_scope("optimizer", "stats"):
+            stats = self._stats(v_l1=jnp.sum(jnp.abs(v)),
+                                grad_norm=jnp.linalg.norm(g_norm_in),
+                                momentum_norm=jnp.linalg.norm(m_bar),
+                                worker_err=errs["worker"],
+                                server_err=errs["server"])
         return x_full, state._replace(**repl), stats
 
     # --- audit probe (observation only; repro.obs.audit builds it) ---------
@@ -648,10 +661,11 @@ class TwoStageOptimizer:
 
     @staticmethod
     def _gather_replica(master_shard: jax.Array, all_axes) -> jax.Array:
-        if all_axes:
-            return jax.lax.all_gather(master_shard.astype(jnp.bfloat16),
-                                      all_axes, tiled=True)
-        return master_shard.astype(jnp.bfloat16)
+        with obs.layer_scope("exchange", "gather_replica"):
+            if all_axes:
+                return jax.lax.all_gather(master_shard.astype(jnp.bfloat16),
+                                          all_axes, tiled=True)
+            return master_shard.astype(jnp.bfloat16)
 
     def _full_params(self, state: StateTree, x, all_axes) -> jax.Array:
         if "master_shard" in state:

@@ -70,6 +70,7 @@ from repro.core import onebit_adam as OB
 from repro.core.compression import padded_length
 from repro.models import transformer as T
 from repro.models.common import ParallelCtx
+from repro.obs import trace as obs
 from repro.optim import (STAT_KEYS, TwoStageOptimizer, from_config,
                          get_optimizer, segments_of)
 from repro.state import (StateLayout, StateTree, init_global_state,
@@ -449,12 +450,13 @@ def flat_grads(params, batch, cfg: ArchConfig, ctx: ParallelCtx,
     grads, total, metrics = _grad_tree(params, batch, cfg, ctx,
                                        aux_weight, accum_steps)
     segs = segments_of(grads, d_pad)
-    if bucket_sizes is not None:
-        return (flat_grad_parts(grads, bucket_sizes, d_pad), segs,
-                total, metrics)
-    g_flat, _ = ravel_pytree(grads)
-    d_r = g_flat.shape[0]
-    g_flat = jnp.pad(g_flat.astype(jnp.float32), (0, d_pad - d_r))
+    with obs.layer_scope("optimizer", "flatten"):
+        if bucket_sizes is not None:
+            return (flat_grad_parts(grads, bucket_sizes, d_pad), segs,
+                    total, metrics)
+        g_flat, _ = ravel_pytree(grads)
+        d_r = g_flat.shape[0]
+        g_flat = jnp.pad(g_flat.astype(jnp.float32), (0, d_pad - d_r))
     return g_flat, segs, total, metrics
 
 
@@ -510,7 +512,8 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
             d_pad, n_dp, block, tsc.n_buckets).sizes
 
     def step(params, opt, batch, lr):
-        flat0, unravel = ravel_pytree(params)
+        with obs.layer_scope("optimizer", "flatten"):
+            flat0, unravel = ravel_pytree(params)
         d_r = flat0.shape[0]
         g_flat, segs, total, metrics = flat_grads(
             params, batch, cfg, ctx, tsc.aux_weight, tsc.accum_steps,
@@ -518,8 +521,9 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
 
         # global -> per-rank views: flatten every non-scalar slot (the
         # per-rank shard of any slot is its length with singleton leads)
-        st = StateTree({k: (v.reshape(-1) if v.ndim else v)
-                        for k, v in opt.items()})
+        with obs.layer_scope("optimizer", "flatten"):
+            st = StateTree({k: (v.reshape(-1) if v.ndim else v)
+                            for k, v in opt.items()})
         sharded = "master_shard" in st
 
         if sharded:
@@ -527,9 +531,11 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
                 g_flat, st, lr, dp_axes=inner_axes, pod_axes=outer_axes,
                 tp_axes=tp_axes, segs=segs, sync=tsc.sync,
                 n_buckets=tsc.n_buckets)
-            new_params = unravel(x_full[:d_r].astype(flat0.dtype))
+            with obs.layer_scope("optimizer", "unflatten"):
+                new_params = unravel(x_full[:d_r].astype(flat0.dtype))
         else:
-            x = jnp.pad(flat0, (0, d_pad - d_r))
+            with obs.layer_scope("optimizer", "flatten"):
+                x = jnp.pad(flat0, (0, d_pad - d_r))
             if tsc.stage == "warmup":
                 new_x, st, stats = optimizer.warmup_update(
                     g_flat, st, x, lr, dp_axes=dp_axes, tp_axes=tp_axes,
@@ -539,36 +545,39 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
                     g_flat, st, lr, x=x, dp_axes=inner_axes,
                     pod_axes=outer_axes, tp_axes=tp_axes, segs=segs,
                     sync=tsc.sync, n_buckets=tsc.n_buckets)
-            new_params = unravel(new_x[:d_r])
+            with obs.layer_scope("optimizer", "unflatten"):
+                new_params = unravel(new_x[:d_r])
 
         # per-rank -> global views, generically (scalars pass through)
-        new_opt = StateTree({k: (st[k].reshape(opt[k].shape)
-                                 if opt[k].ndim else st[k])
-                             for k in opt})
-
+        with obs.layer_scope("optimizer", "unflatten"):
+            new_opt = StateTree({k: (st[k].reshape(opt[k].shape)
+                                     if opt[k].ndim else st[k])
+                                 for k in opt})
         # metrics: mean over dp (a no-op while replicated; the honest
         # cross-rank mean in the "local" layout); v_l1 summed over model
         # shards = the paper's fused-variance norm (Fig. 2)
-        out_metrics = {k: jax.lax.pmean(v, dp_axes) if dp_axes else v
-                       for k, v in metrics.items()}
-        v_l1 = stats["v_l1"]
-        if sharded and dp_axes:   # v sharded over dp: SUM the shard norms
-            v_l1 = jax.lax.psum(v_l1, dp_axes)
-        elif tsc.layout == "local" and dp_axes:
-            v_l1 = jax.lax.pmean(v_l1, dp_axes)
-        if ctx.tp_axis:
-            v_l1 = jax.lax.psum(v_l1, ctx.tp_axis)
-        out_metrics["v_l1"] = v_l1
-        # the remaining uniform STAT_KEYS (grad/momentum/EF-residual
-        # norms) are per-model-rank diagnostics: dp-meaned like the loss
-        # metrics (honest across divergent local state), not combined
-        # over tp (a cross-shard L2 would need the squared-sum psum)
-        for k, v in stats.items():
-            if k != "v_l1":
-                out_metrics[k] = (jax.lax.pmean(v, dp_axes)
-                                  if dp_axes else v)
-        out_metrics["total"] = (jax.lax.pmean(total, dp_axes)
-                                if dp_axes else total)
+        with obs.layer_scope("optimizer", "stats"):
+            out_metrics = {k: jax.lax.pmean(v, dp_axes) if dp_axes else v
+                           for k, v in metrics.items()}
+            v_l1 = stats["v_l1"]
+            if sharded and dp_axes:   # v sharded over dp: SUM the shards
+                v_l1 = jax.lax.psum(v_l1, dp_axes)
+            elif tsc.layout == "local" and dp_axes:
+                v_l1 = jax.lax.pmean(v_l1, dp_axes)
+            if ctx.tp_axis:
+                v_l1 = jax.lax.psum(v_l1, ctx.tp_axis)
+            out_metrics["v_l1"] = v_l1
+            # the remaining uniform STAT_KEYS (grad/momentum/EF-residual
+            # norms) are per-model-rank diagnostics: dp-meaned like the
+            # loss metrics (honest across divergent local state), not
+            # combined over tp (a cross-shard L2 would need the
+            # squared-sum psum)
+            for k, v in stats.items():
+                if k != "v_l1":
+                    out_metrics[k] = (jax.lax.pmean(v, dp_axes)
+                                      if dp_axes else v)
+            out_metrics["total"] = (jax.lax.pmean(total, dp_axes)
+                                    if dp_axes else total)
         return new_params, new_opt, out_metrics
 
     _cache: Dict[frozenset, Any] = {}

@@ -6,6 +6,10 @@ Pins, per ISSUE acceptance:
     MetricBuffer device→host path;
   * the non-finite v_l1 guard (VarianceMonitor rejection + WarmupSwitch
     warning callback — a NaN can neither trigger nor block the freeze);
+  * layer scopes: the compiled step is the same program with and
+    without its obs::<layer>::<part> scopes (metadata stripped), and
+    every op it runs lies under exactly one of them, outside a short
+    list of exceptions;
   * trace spans: naming, the disabled-is-nullcontext fast path, and
     TELEMETRY NEUTRALITY — with tracing on, the train step's compiled
     collective signature and the losses it produces are unchanged
@@ -17,9 +21,11 @@ Pins, per ISSUE acceptance:
   * per-step telemetry overhead stays bounded (pinned, generous);
   * report folding + the end-to-end --telemetry training log.
 """
+import contextlib
 import json
 import math
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -292,6 +298,146 @@ class TestTrace:
                                     ("all-gather", "f32[512], u8[64]"),
                                     ("all-reduce", "f32[512]")]))
         assert TR.collective_signature("%d = f32[2] dot(%a)") == ()
+
+
+# --------------------------------------------------------------------------
+# layer scopes in the compiled train step
+# --------------------------------------------------------------------------
+
+_HLO_COMP = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) \(.*\{$")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a layer scope as one op_name component, bare or as the argument of the
+# autodiff transform it sits directly under: "jvp(obs::model::head)"
+_LAYER_SCOPE = re.compile(
+    r"^(?:[A-Za-z_]+\()*obs::(?:model|optimizer|exchange)::[A-Za-z0-9_]+"
+    r"\)*$")
+# instructions that execute nothing
+_FREE = ("parameter", "constant", "bitcast", "get-tuple-element", "tuple")
+# the named ops that lie under no layer scope: the layer scan's own loop
+# (the while, its counter and condition, the per-layer slices of the
+# stacked parameters and the stacking of the outputs, the remat call's
+# boundary) and, at the top of the gradient computation, the zero
+# initial values of the stacked outputs and the sum of the per-layer
+# auxiliary losses
+_UNSCOPED_OK = re.compile(
+    r"/while(?:/(?:body|cond)/[^/]+|/body/closed_call(?:/remat2)?)?$"
+    r"|^jit\(step\)/(?:transpose\()?jvp\(\)\)?/"
+    r"(?:broadcast_in_dim|reduce_sum)$")
+
+
+def _strip_metadata(hlo: str) -> str:
+    """Compiled HLO text without what names and source positions add:
+    each instruction's metadata and the module's stack-frame tables."""
+    hlo = re.sub(r", metadata=\{[^}]*\}", "", hlo)
+    return "\n".join(
+        line for line in hlo.splitlines() if not re.match(
+            r"(\d+ |FileNames|FunctionNames|FileLocations|StackFrames)",
+            line))
+
+
+def _executed_ops(hlo: str):
+    """(opcode, op_name) of each named instruction the device runs as an
+    op of its own: those of the entry computation and of the loops'
+    bodies, not those inside a fusion or a reducer."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _HLO_COMP.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None and _HLO_INSTR.match(line):
+            cur.append(_HLO_INSTR.match(line).group(2))
+    inner = set()
+    for body in comps.values():
+        for rest in body:
+            inner.update(re.findall(r"calls=%([\w.\-]+)", rest))
+            if " call(" not in rest:
+                inner.update(re.findall(r"to_apply=%([\w.\-]+)", rest))
+    for name, body in comps.items():
+        if name in inner:
+            continue
+        for rest in body:
+            src = _OP_NAME.search(rest)
+            if src:
+                yield _HLO_OPCODE.search(" " + rest).group(1), src.group(1)
+
+
+@pytest.fixture(scope="module")
+def step_hlo():
+    """Compiled HLO of the bert-base-smoke warmup and compressed steps on
+    one device, with and without the layer scopes."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as T
+    from repro.train.step import (TrainStepConfig, init_train_state,
+                                  make_train_step)
+
+    cfg = get_config("bert-base-smoke")
+    mesh = make_mesh((1, 1), ("data", "model"))
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0),
+                                                  tp=1))
+    batch = {k: jax.ShapeDtypeStruct((8, 32), jnp.int32)
+             for k in ("tokens", "labels")}
+
+    def compile_step(stage):
+        step = make_train_step(cfg, mesh, TrainStepConfig(
+            stage=stage, block_size=512), donate=False)
+        opt = init_train_state(cfg, mesh, block=512, abstract=True,
+                               optimizer=step.optimizer)
+        return step.build(batch).lower(params, opt, batch,
+                                       jnp.float32(1e-3)).compile().as_text()
+
+    out = {}
+    for stage in ("warmup", "compressed"):
+        out[stage, True] = compile_step(stage)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(TR, "layer_scope",
+                       lambda layer, part: contextlib.nullcontext())
+            out[stage, False] = compile_step(stage)
+    return out
+
+
+@pytest.mark.parametrize("stage", ["warmup", "compressed"])
+class TestLayerScopes:
+    def test_scopes_are_metadata_only(self, step_hlo, stage):
+        scoped, bare = step_hlo[stage, True], step_hlo[stage, False]
+        assert "obs::model::attention" in scoped
+        assert "obs::" not in bare
+        assert _strip_metadata(scoped) == _strip_metadata(bare)
+
+    def test_every_op_under_one_layer_scope(self, step_hlo, stage):
+        ops = [(code, src) for code, src in _executed_ops(
+            step_hlo[stage, True]) if code not in _FREE]
+        assert len(ops) > 100
+        scopes = {src: [p for p in src.split("/") if _LAYER_SCOPE.match(p)]
+                  for _, src in ops}
+        stray = sorted({src for src, found in scopes.items()
+                        if len(found) != 1 and not _UNSCOPED_OK.search(src)})
+        assert not stray, stray
+        # every scope the step opens reaches the compiled program (inside
+        # a fusion, where the fusion is named after another instruction)
+        named = {re.search(r"obs::\w+::\w+", p).group(0)
+                 for src in _OP_NAME.findall(step_hlo[stage, True])
+                 for p in src.split("/") if _LAYER_SCOPE.match(p)}
+        want = {"obs::model::embed", "obs::model::norm", "obs::model::mlp",
+                "obs::model::attention", "obs::model::head",
+                "obs::optimizer::flatten", "obs::optimizer::momentum",
+                "obs::optimizer::update", "obs::optimizer::stats",
+                "obs::optimizer::unflatten"}
+        want.add("obs::exchange::onebit" if stage == "compressed"
+                 else "obs::exchange::allreduce")
+        assert want <= named, want - named
+        # forward, backward and the recomputed forward of attention
+        attn = [src for src in scopes if "obs::model::attention/" in src]
+        assert any(s.startswith("jit(step)/jvp()/") for s in attn)
+        assert any(s.startswith("jit(step)/transpose(jvp())/")
+                   and "rematted_computation" not in s for s in attn)
+        assert any("/rematted_computation/" in s for s in attn)
 
 
 # --------------------------------------------------------------------------

@@ -80,6 +80,20 @@ class TestScopeGrammar:
         s = prof.parse_scope(name)
         assert prof.cell_key(s) == ("p", None, 0, "AllReduce", "intra")
 
+    @pytest.mark.parametrize("plan,bucket", [("flat/onebit", None),
+                                             ("onebit", 2)])
+    def test_grid_scope_under_layer_scope(self, plan, bucket):
+        # the exchange's layer scope wraps the executors' grid scopes: the
+        # plan must not run back into the enclosing obs:: component
+        name = ("jit(step)/obs::exchange::onebit/"
+                + span_name(plan, 1, "AllToAll", "intra", bucket=bucket)
+                + "/all_to_all")
+        assert prof.cell_key(prof.parse_scope(name)) == (
+            plan, bucket, 1, "AllToAll", "intra")
+        assert prof.parse_scope(
+            "jit(step)/jvp()/while/body/closed_call/obs::model::attention"
+            "/dot_general") is None
+
     def test_legacy_at_separator_still_parses(self):
         s = prof.parse_scope("obs::hier_onebit::b2.s1::AllToAll@cross")
         assert prof.cell_key(s) == ("hier_onebit", 2, 1, "AllToAll",
