@@ -78,7 +78,10 @@ class ArchConfig:
     #          (trades memory for ~25% fewer backward FLOPs)
     remat_policy: str = "block"
     attn_chunk: int = 2048         # KV chunk for the online-softmax path
-    attn_impl: str = "auto"        # "full" | "chunked" | "auto"
+    # "full" | "chunked" | "pallas" (the flash-attention kernels) | "auto"
+    # (the kernels from sequence 512 where the shape fits them, else full
+    # or chunked; models/attention.FLASH_MIN_SEQ)
+    attn_impl: str = "auto"
     source: str = ""               # citation
 
     def __post_init__(self):

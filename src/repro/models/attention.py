@@ -1,6 +1,8 @@
 """GQA attention with Megatron TP sharding, causal/sliding-window masks,
-a chunked online-softmax path for long prefill, and KV-cached decode with
-optional flash-decoding-style sequence sharding over the dp axes.
+the Pallas flash-attention op (``kernels.flash_attn``, forward and
+backward) for training and prefill, a chunked online-softmax path for
+long prefill, and KV-cached decode with optional flash-decoding-style
+sequence sharding over the dp axes.
 
 Per-rank layout (tp = ctx.tp):
   wq : (d, Hq_l * hd)   column-parallel, Hq_l = padded_heads / tp
@@ -20,11 +22,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_attn import ops as fa
 from repro.models.common import (ParallelCtx, apply_rope, dense, f_reduce,
                                  g_copy, grouped_param, init_linear,
                                  rope_tables)
 
 NEG_INF = -1e30
+# "auto" takes the flash-attention kernels from this sequence length up.
+# On a v5e, model forward and backward (block remat): bert-base 32 x 512
+# trains 37% faster with them, but bert-large is slower at 32 x 128
+# (77.3 against 68.4 ms with _sdpa) and 16 x 256 (75.7 against 74.2 ms),
+# where the S x S scores are small; bert-base 128 x 128 gains 3.5%.
+FLASH_MIN_SEQ = 512
 
 
 def shard_dims(cfg: ArchConfig, tp: int) -> Tuple[int, int, int]:
@@ -178,14 +187,12 @@ def attn_forward(p, x: jax.Array, cfg: ArchConfig, ctx: ParallelCtx,
     k, v = _repeat_kv(k0, n_rep), _repeat_kv(v0, n_rep)
     use_chunked = (cfg.attn_impl == "chunked" or
                    (cfg.attn_impl == "auto" and s > 4 * cfg.attn_chunk))
-    if cfg.attn_impl == "pallas":
-        # Pallas flash-attention kernel (forward-only: inference/prefill;
-        # training needs the bwd kernel — use "chunked" there)
-        from repro.kernels.flash_attn import ops as fa
-        o = fa.flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=cfg.causal, window=cfg.window,
-        ).transpose(0, 2, 1, 3)
+    # the Pallas kernels, forward and backward, wherever the shape fits
+    # them from FLASH_MIN_SEQ to the length at which "auto" turns chunked
+    if cfg.attn_impl == "pallas" or (
+            cfg.attn_impl == "auto" and not use_chunked
+            and s >= FLASH_MIN_SEQ and fa.supports(s, hq, cfg.head_dim)):
+        o = fa.flash_attention(q, k, v, causal=cfg.causal, window=cfg.window)
     elif use_chunked and s % cfg.attn_chunk == 0 and cfg.causal:
         o = _sdpa_chunked(q, k, v, 0, cfg.window, cfg.attn_chunk)
     else:

@@ -9,7 +9,10 @@ and not on the chip.  Nothing runs, so nothing here is a time.
 The topology is described inside a fixture: only the worker that runs
 this file loads the TPU library.
 """
+import dataclasses
 import os
+import re
+import sys
 import time
 
 import numpy as np
@@ -23,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 # the padded flat parameter length of bert-large on one chip, block 4096
 BERT_LARGE_D = 364_564_480
 HBM_BYTES = 16 * 2**30
+BENCH = os.path.join(os.path.dirname(__file__), "..", "bench")
 
 
 @pytest.fixture(scope="module")
@@ -81,15 +85,32 @@ class TestKernels:
                      x, x, x, x, lr)
         assert "tpu_custom_call" in c.as_text()
 
+    @staticmethod
+    def _attention_text(one_chip, shape, **kw) -> str:
+        from repro.kernels.flash_attn import ops
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+        def fwd_bwd(q, k, v, do):
+            o, vjp = jax.vjp(lambda a, b, c: ops.flash_attention(
+                a, b, c, **kw), q, k, v)
+            return o, vjp(do)
+        return _compile(fwd_bwd, x, x, x, x).as_text()
+
     @pytest.mark.parametrize("causal", [True, False])
     def test_flash_attention(self, one_chip, causal):
-        from repro.kernels.flash_attn import kernel as K
-        q = jax.ShapeDtypeStruct((1, 16, 512, 64), jnp.float32,
-                                 sharding=one_chip)
-        c = _compile(lambda a, b, v: K.flash_attention(a, b, v,
-                                                       causal=causal),
-                     q, q, q)
-        assert "tpu_custom_call" in c.as_text()
+        """The attention op's forward and backward kernels at the
+        bert-base cell's shape: 32 x 512, 12 heads of 64, bf16."""
+        text = self._attention_text(one_chip, (32, 512, 12, 64),
+                                    causal=causal)
+        assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
+
+    @pytest.mark.parametrize("window", [None, 1000])
+    def test_flash_attention_tiled(self, one_chip, window):
+        """Sequence 4096 takes the online-softmax forward and the backward
+        that sums dq across kv blocks, with di from the op."""
+        text = self._attention_text(one_chip, (1, 4096, 8, 128),
+                                    causal=True, window=window)
+        assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
 
 
 class TestSignPack:
@@ -109,14 +130,15 @@ class TestSignPack:
         assert c.memory_analysis().temp_size_in_bytes < 2**30
 
 
-def _bert_large_step(topo, stage: str, use_kernel: bool):
-    """The donated bert-large step program of ``launch.train`` on one
-    described chip, batch 32 x sequence 128, from shapes only."""
+def _train_step(topo, arch, batch: int, seq: int, stage: str,
+                use_kernel: bool = False):
+    """The donated step program of ``launch.train`` on one described
+    chip, from shapes only; ``arch`` is a registered name or a config."""
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.train.step import (TrainStepConfig, init_train_state,
                                   make_train_step)
-    cfg = get_config("bert-large")
+    cfg = get_config(arch) if isinstance(arch, str) else arch
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
     tsc = TrainStepConfig(stage=stage, use_kernel=use_kernel)
     fn = make_train_step(cfg, mesh, tsc)
@@ -132,16 +154,21 @@ def _bert_large_step(topo, stage: str, use_kernel: bool):
     opt = init_train_state(cfg, mesh, abstract=True,
                            optimizer=tsc.build_optimizer())
     rows = NamedSharding(mesh, P("data", None))
-    batch = {k: jax.ShapeDtypeStruct((32, 128), dt, sharding=rows)
-             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
-                           ("loss_mask", jnp.float32))}
+    data = {k: jax.ShapeDtypeStruct((batch, seq), dt, sharding=rows)
+            for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
+                          ("loss_mask", jnp.float32))}
     lr = jax.ShapeDtypeStruct((), jnp.float32,
                               sharding=NamedSharding(mesh, P()))
     t0 = time.time()
-    compiled = fn.build(batch).lower(shapes(params, fn.param_specs),
-                                     shapes(opt, fn.opt_specs), batch,
-                                     lr).compile()
+    compiled = fn.build(data).lower(shapes(params, fn.param_specs),
+                                    shapes(opt, fn.opt_specs), data,
+                                    lr).compile()
     return compiled, time.time() - t0
+
+
+# the optimizer's Pallas kernels, by the jitted calls they are named after
+OPTIMIZER_KERNELS = ("jit(_adam_call)", "jit(_ef_compress_call)",
+                     "jit(_decompress_call)")
 
 
 @pytest.mark.parametrize("stage,use_kernel", [
@@ -150,7 +177,8 @@ def _bert_large_step(topo, stage: str, use_kernel: bool):
 def test_bert_large_step_fits_one_chip(topo, stage, use_kernel):
     """The compiler itself refuses a program over the chip's HBM; the
     donated parameters and optimizer state are held once (aliased)."""
-    compiled, seconds = _bert_large_step(topo, stage, use_kernel)
+    compiled, seconds = _train_step(topo, "bert-large", 32, 128, stage,
+                                    use_kernel)
     ma = compiled.memory_analysis()
     print(f"{stage} kernels={use_kernel}: compiled in {seconds:.1f}s, "
           f"peak {ma.peak_memory_in_bytes / 2**30:.2f} GiB")
@@ -158,7 +186,51 @@ def test_bert_large_step_fits_one_chip(topo, stage, use_kernel):
     assert ma.argument_size_in_bytes > 9 * 2**30
     assert ma.argument_size_in_bytes - ma.alias_size_in_bytes < 2**20
     assert ma.peak_memory_in_bytes <= HBM_BYTES
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+    text = compiled.as_text()
+    # sequence 128 is below FLASH_MIN_SEQ, so attention goes through XLA;
+    # the optimizer's kernels are there with use_kernel only
+    assert "flash_attn" not in text
+    assert any(k in text for k in OPTIMIZER_KERNELS) == use_kernel
     # a minute would mean the compressed exchange fell back into the
     # pathological layouts the flat-vector views avoid
     assert seconds < 120
+
+
+def test_bert_base_step_keeps_scores_in_vmem(topo):
+    """The bert-base step of the benchmark's cell (32 x 512, compressed):
+    attention runs through both kernels, and no buffer of the step holds
+    the 32 x 12 x 512 x 512 scores or probabilities."""
+    compiled, seconds = _train_step(topo, "bert-base", 32, 512,
+                                    "compressed")
+    text = compiled.as_text()
+    print(f"bert-base 32 x 512: compiled in {seconds:.1f}s, peak "
+          f"{compiled.memory_analysis().peak_memory_in_bytes / 2**30:.2f} "
+          f"GiB")
+    assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
+    assert not re.search(r"(?:f32|bf16)\[32,12,512,512\]", text)
+
+
+def test_attention_kernels_under_attention_scope(topo):
+    """The kernels' forward, recomputed forward and backward carry the
+    ``obs::model::attention`` layer scope in their op_name, so the
+    benchmark's scope split (``harness.scopes``) counts them under
+    attention, the custom_vjp's backward included."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    from harness import scopes
+    from repro.configs import get_config
+    # the smoke config's 64-row attn_chunk would send S = 512 chunked
+    cfg = dataclasses.replace(get_config("bert-base-smoke"), attn_chunk=2048)
+    compiled, _ = _train_step(topo, cfg, 2, 512, "warmup")
+    found = set()
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(flash_attn_(?:fwd|bwd))[.\d]* = ", line)
+        if not m:
+            continue
+        src = re.search(r'op_name="([^"]*)"', line).group(1)
+        scope, remat = scopes.scope_of(src)
+        assert scope == "obs::model::attention", src
+        found.add((m.group(1), remat, "transpose(" in src))
+    assert found == {("flash_attn_fwd", False, False),   # forward
+                     ("flash_attn_fwd", True, True),     # recomputed
+                     ("flash_attn_bwd", False, True)}    # backward
