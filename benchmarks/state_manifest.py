@@ -42,7 +42,7 @@ def build_manifest(d: int = D, n_inner: int = N_INNER,
                 n_segments=8,
                 dp_sizes=(n_outer, n_inner), tp=1)
             out["grid"][f"{layout}/{topo}"] = layout_manifest(
-                opt.state_slots(layout), ctx, block=block)
+                opt.state_slots(layout, topo), ctx, block=block)
     return out
 
 

@@ -86,7 +86,8 @@ from repro.perf import resolve_device
 from repro.state import load_train_state, save_train_state
 from repro.train.step import (TrainStepConfig, _flat_dim, init_train_state,
                               make_train_step, mesh_axes, pod_split,
-                              state_layout_ctx, train_state_specs)
+                              state_layout_ctx, train_slots,
+                              train_state_specs)
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
 
@@ -629,7 +630,8 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
             specs, tree, is_leaf=lambda x: isinstance(x, P))
 
     param_specs = T.param_specs(cfg, base_tsc.model_axis, tp)
-    opt_specs = train_state_specs(mesh, base_tsc.model_axis, layout, optim)
+    opt_specs = train_state_specs(mesh, base_tsc.model_axis, layout, optim,
+                                  topology)
     key = jax.random.PRNGKey(seed)
     params = T.init_params(cfg, key, tp=tp)
     # the zero optimizer state is made in place on every device: built
@@ -644,7 +646,7 @@ def run(arch: str, steps: int, batch: int, seq: int, mesh_shape,
     # EF slots are SAVED in the canonical (serial) global-element keying
     # and scattered into this run's bucket partition on load, so
     # checkpoints are portable across --pipeline off/N/M by construction
-    slots = optim.state_slots(layout)
+    slots = train_slots(mesh, base_tsc.model_axis, layout, topology, optim)
     state_ctx = state_layout_ctx(cfg, mesh, block=spec.block_size,
                                  topology=topology)
     start_step = 0

@@ -92,8 +92,8 @@ def make_audit_probe(cfg, mesh, tsc):
     from repro.optim.base import AUDIT_SCALAR_KEYS, AUDIT_SEG_KEYS
     from repro.state import StateTree
     from repro.train.step import (_ctx, _flat_dim, _select, batch_specs,
-                                  flat_grads, mesh_axes, pod_split,
-                                  train_state_specs)
+                                  flat_grads, mesh_axes, mesh_topology,
+                                  pod_split, train_state_specs)
 
     tsc = tsc.normalized()
     assert tsc.layout in ("replicated", "local"), (
@@ -107,8 +107,9 @@ def make_audit_probe(cfg, mesh, tsc):
     ctx = _ctx(mesh, tsc.model_axis)
     tp_axes = (tsc.model_axis,) if tp > 1 else ()
     pspecs = T.param_specs(cfg, tsc.model_axis, tp)
-    osp = train_state_specs(mesh, tsc.model_axis, tsc.layout, optimizer)
-    if tsc.topology == "hier" and len(dp_axes) > 1:
+    osp = train_state_specs(mesh, tsc.model_axis, tsc.layout, optimizer,
+                            tsc.topology)
+    if mesh_topology(mesh, tsc.topology, tsc.model_axis) == "hier":
         inner_axes, outer_axes, _, _ = pod_split(dp_axes, dp_sizes)
     else:
         inner_axes, outer_axes = dp_axes, ()

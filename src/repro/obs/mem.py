@@ -165,7 +165,7 @@ def predict_ledger(cfg, mesh, *, optim=None, layout: str = "replicated",
     pipelined watermark timeline."""
     from repro.analysis.model_math import activation_bytes, param_bytes
     from repro.state import state_bytes
-    from repro.train.step import mesh_axes, state_layout_ctx
+    from repro.train.step import mesh_axes, state_layout_ctx, train_slots
     dp_axes, dp_sizes, tp = mesh_axes(mesh)
     n_dp = 1
     for s in dp_sizes:
@@ -175,7 +175,8 @@ def predict_ledger(cfg, mesh, *, optim=None, layout: str = "replicated",
     if optim is None:
         from repro.optim.base import TwoStageOptimizer
         optim = TwoStageOptimizer()
-    slots = optim.state_slots(layout)
+    slots = train_slots(mesh, layout=layout, topology=topology,
+                        optimizer=optim)
     pbytes = float(param_bytes(cfg, tp, param_dtype_bytes))
     # the padded flat f32 exchange buffer IS the gradient's steady-state
     # residency; the unflattened grad tree is transient (-> activations

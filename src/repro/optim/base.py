@@ -55,6 +55,7 @@ import numpy as np
 from repro.core import comm
 from repro.obs import trace as obs
 from repro.optim.compressors import Compressor, OneBitCompressor
+from repro.plan.schedules import needs_outer_ef
 from repro.state import (SlotSpec, StateLayout, StateTree, ef_errs,
                          init_rank_state)
 
@@ -193,18 +194,22 @@ class TwoStageOptimizer:
     name: str = "?"
 
     # --- declared state ----------------------------------------------------
-    def state_slots(self, layout: str = "replicated"
-                    ) -> Tuple[SlotSpec, ...]:
+    def state_slots(self, layout: str = "replicated",
+                    topology: str = "flat") -> Tuple[SlotSpec, ...]:
         """The optimizer family's state, declared once (repro.state).
 
         ``layout`` selects the replication of the adaptive state:
         ``replicated`` (paper), ``local`` (per-dp-rank m/v/scale —
         required when ``sync_due`` can skip), ``zero1`` (``v`` + f32
         master weights dp-sharded).  EF slots are identical across
-        layouts: error state is inherently per-worker.  Optimizers with
-        extra state override this and append their slots.
+        layouts: error state is inherently per-worker.  ``topology``
+        (``flat`` or ``hier``, the exchange the state serves) adds the
+        cross-pod EF slots where that schedule consumes them.
+        Optimizers with extra state override this and append their
+        slots.
         """
         assert layout in LAYOUTS, layout
+        assert topology in ("flat", "hier"), topology
         adaptive = "per_dp_rank" if layout == "local" else "replicated"
         slots = [SlotSpec("m", "per_param", "replicated"
                           if layout != "local" else "per_dp_rank")]
@@ -223,15 +228,19 @@ class TwoStageOptimizer:
             SlotSpec("scale", "per_segment", adaptive),
             SlotSpec("count", "scalar", dtype="int32"),
             SlotSpec("v_step", "scalar", dtype="int32"),
-            # cross-pod EF slots of the hierarchical schedule: consumed
-            # only by sparse compressors on "hier", untouched zeros
-            # otherwise (declared unconditionally so the state schema —
-            # and checkpoints — do not depend on the compressor choice)
-            SlotSpec("outer_err", "per_chunk", "per_dp_rank",
-                     chunk_of="server", ef="outer", bucket_keyed=True),
-            SlotSpec("outer_ag_err", "per_chunk", "per_dp_rank",
-                     chunk_of="total", ef="outer_ag", bucket_keyed=True),
         ]
+        if topology == "hier" and needs_outer_ef(self.compressor):
+            # the hierarchical schedule's cross-pod EF loops, which only
+            # sparse compressors run (plan.schedules.hier_schedule);
+            # checkpoints move between schemas by slot diff
+            # (state.checkpoint)
+            slots += [
+                SlotSpec("outer_err", "per_chunk", "per_dp_rank",
+                         chunk_of="server", ef="outer", bucket_keyed=True),
+                SlotSpec("outer_ag_err", "per_chunk", "per_dp_rank",
+                         chunk_of="total", ef="outer_ag",
+                         bucket_keyed=True),
+            ]
         return tuple(slots)
 
     def init_state(self, d: int, n_dp: int = 1, n_segments: int = 1,
@@ -249,7 +258,8 @@ class TwoStageOptimizer:
         ctx = StateLayout(d=d, n_dp=n, n_srv=n_srv,
                           n_outer=max(n // n_srv, 1),
                           n_segments=max(n_segments, 1))
-        return init_rank_state(self.state_slots(layout), ctx)
+        topology = "flat" if n_inner is None else "hier"
+        return init_rank_state(self.state_slots(layout, topology), ctx)
 
     @staticmethod
     def _stats(v_l1, grad_norm, momentum_norm, state=None,
@@ -502,9 +512,10 @@ class TwoStageOptimizer:
 
         # the declared ef= fields ARE the state-slot -> plan-slot map
         # (EF slots are layout-invariant, so any layout's declaration
-        # serves; subclasses declaring extra EF slots are picked up)
+        # serves; subclasses declaring extra EF slots are picked up);
+        # the state holds the ones its topology declared
         ef_slots = tuple(s for s in self.state_slots(
-            "zero1" if sharded else "replicated")
+            "zero1" if sharded else "replicated", "hier")
             if s.ef is not None and s.name in state)
         m_bar, errs = comm.compressed_exchange(
             m_local, ef_errs(state, ef_slots), dp_axes, pod_axes,

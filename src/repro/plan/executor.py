@@ -30,7 +30,10 @@ op lowers inside a ``jax.named_scope`` carrying its
 profiler trace attributes device time to the same (bucket, stage,
 stream) grid the cost model prices.  Scopes are HLO *metadata* only —
 the compiled collectives are identical on and off (pinned by
-tests/test_obs.py) — and a shared nullcontext when disabled.
+tests/test_obs.py) — and a shared nullcontext when disabled.  Always
+on, and as metadata only too, each rank's work as a server (the average
+of the chunks it received, the compression of what it sends on) lies
+under the ``obs::exchange::server`` layer scope (``server_scope``).
 """
 from __future__ import annotations
 
@@ -40,6 +43,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as obs
 from repro.obs.trace import op_scope
 from repro.plan.ir import (AllGather, AllReduce, AllToAll, Broadcast,
                            CollectiveOp, CommPlan, ReduceScatter)
@@ -82,24 +86,36 @@ def chunk_all_to_all(p: jax.Array, n: int, axes) -> jax.Array:
     return recv.reshape(n, chunk)
 
 
+def server_scope():
+    """The ``obs::exchange::server`` layer scope: a rank's work as the
+    server of its chunk -- the average of the chunks it received and
+    the compression (with its error feedback) of what it sends on.  The
+    collectives themselves stay outside it."""
+    return obs.layer_scope("exchange", "server")
+
+
 def _exec_all_to_all(op: AllToAll, comp, value, errs):
     payload, errs = _compress(op, comp, value, errs)
     if op.axes:
         recv = [chunk_all_to_all(p, op.n, op.axes) for p in payload]
-        vals = jax.vmap(lambda *leaves: comp.decompress(tuple(leaves)))(*recv)
-        if op.combine == "mean":
-            value = jnp.mean(vals, axis=0)
-        else:
-            value = jnp.sum(vals, axis=0)
+        with server_scope():
+            vals = jax.vmap(lambda *leaves: comp.decompress(tuple(leaves)))(
+                *recv)
+            if op.combine == "mean":
+                value = jnp.mean(vals, axis=0)
+            else:
+                value = jnp.sum(vals, axis=0)
     else:
         # degenerate single-group: the compress/decompress roundtrip still
         # runs so single-device numerics match the distributed path
-        value = comp.decompress(payload)
+        with server_scope():
+            value = comp.decompress(payload)
     return value, errs
 
 
 def _exec_all_gather(op: AllGather, comp, value, errs):
-    payload, errs = _compress(op, comp, value, errs)
+    with server_scope():
+        payload, errs = _compress(op, comp, value, errs)
     if op.axes:
         out = tuple(jax.lax.all_gather(p, op.axes, tiled=op.tiled)
                     for p in payload)

@@ -168,16 +168,19 @@ def _invalid(topology, compressor, block_size, d, why,
 
 
 def layout_state_bytes(spec: ClusterSpec, d_pad: int, topology: str,
-                       layout: str) -> int:
+                       layout: str, comp=None) -> int:
     """Per-rank optimizer-state bytes, read off the DECLARED slot
     extents (repro.state) — zero1's dp-sharded ``v``/master chunks and
-    hier's inner-sized EF chunks price themselves."""
+    hier's inner-sized EF chunks price themselves, and the cross-pod EF
+    slots count where ``comp`` (default: 1-bit) needs them on hier."""
     from repro.optim.base import TwoStageOptimizer  # lazy: no cycle
     from repro.state import StateLayout, state_bytes
     n_srv = spec.n_inner if topology == "hier" else spec.n_total
     ctx = StateLayout(d=d_pad, n_dp=spec.n_total, n_srv=n_srv,
                       n_outer=spec.n_outer if topology == "hier" else 1)
-    return state_bytes(TwoStageOptimizer().state_slots(layout), ctx)
+    opt = (TwoStageOptimizer() if comp is None
+           else TwoStageOptimizer(compressor=comp))
+    return state_bytes(opt.state_slots(layout, topology), ctx)
 
 
 def build_candidate(spec: ClusterSpec, d: int, topology: str,
@@ -296,7 +299,7 @@ def build_candidate(spec: ClusterSpec, d: int, topology: str,
                      use_kernel=use_kernel, t_compute=t_comp,
                      layout=layout,
                      state_bytes_per_rank=layout_state_bytes(
-                         spec, d_pad, topology, layout),
+                         spec, d_pad, topology, layout, comp),
                      wire_watermark_bytes=watermark,
                      overlap_bwd=bool(overlap_bwd),
                      t_bwd=t_bwd_eff,
@@ -317,8 +320,12 @@ def enumerate_candidates(spec: ClusterSpec, d: int,
                          t_bwd: float = 0.0,
                          ready_times_fn=None
                          ) -> Tuple[Candidate, ...]:
-    from repro.optim.compressors import list_compressors
+    from repro.optim.compressors import get_compressor, list_compressors
     names = list(compressors) if compressors else list_compressors()
+
+    def comp(name, block):
+        return get_compressor(name, **dict(compressor_kwargs or {},
+                                           block_size=block))
     out = []
     for topo in topologies:
         assert topo in TOPOLOGIES, topo
@@ -349,7 +356,8 @@ def enumerate_candidates(spec: ClusterSpec, d: int,
                                         state_bytes_per_rank=(
                                             layout_state_bytes(
                                                 spec, base.d_padded,
-                                                topo, lay)
+                                                topo, lay, comp(name,
+                                                                block))
                                             if base.valid else 0))
                                 out.extend(dataclasses.replace(
                                     c, sync_interval=max(si, 1))

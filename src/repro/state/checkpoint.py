@@ -13,8 +13,12 @@ the slot-registry-driven layer the training driver uses:
     slot diff (registry vs archive) and start at their zeros template
     (this replaces the old hand-maintained ``outer_err`` backfill
     special case — any slot a future optimizer declares gets the same
-    treatment for free); bucket-keyed slots are then scattered into the
-    resuming run's bucket partition.  Checkpoints written by the
+    treatment for free); slots the archive holds and the registry does
+    not declare for this run (the hierarchical schedule's cross-pod EF
+    slots, resumed on a run that never consumes them) are dropped, with
+    a warning if they held anything but zeros; bucket-keyed slots are
+    then scattered into the resuming run's bucket partition.
+    Checkpoints written by the
     bucket-major era (meta ``n_buckets=k`` without the canonical flag)
     are canonicalised from their recorded ``k`` on the way in.
 """
@@ -32,13 +36,17 @@ from repro.state.slots import SlotSpec, StateLayout, StateTree
 EF_LAYOUT_CANONICAL = "canonical"
 
 
+def _archive_slot(key: str) -> Tuple[str, str]:
+    """(part, name) of a ``(params, state)`` archive key: part ``"1"``
+    holds the state, whose leaves are keyed ``.<slot>``."""
+    part, leaf = key.split("|")[0], key.split("|")[-1]
+    return part, leaf[1:] if leaf.startswith(".") else leaf
+
+
 def slot_diff(state_template: StateTree, archive_keys: Sequence[str]
               ) -> Tuple[str, ...]:
     """Slots the registry declares that the archive predates."""
-    present = set()
-    for k in archive_keys:
-        leaf = k.split("|")[-1]
-        present.add(leaf[1:] if leaf.startswith(".") else leaf)
+    present = {_archive_slot(k)[1] for k in archive_keys}
     return tuple(n for n in state_template if n not in present)
 
 
@@ -63,6 +71,14 @@ def load_train_state(path: str, params_template: Any,
     meta = load_meta(path)
     with np.load(path) as data:
         archive_keys = [k for k in data.files if not k.startswith("__")]
+        lost = sorted(name for k in archive_keys
+                      for part, name in [_archive_slot(k)]
+                      if part == "1" and name not in state_template
+                      and np.any(data[k]))
+    if lost:
+        warnings.warn(
+            f"checkpoint {path} holds state slots {lost} that this run "
+            "does not declare; their non-zero values are dropped")
     missing = slot_diff(state_template, archive_keys)
     if missing:
         # slot-registry-driven backfill: new slots start at their zeros

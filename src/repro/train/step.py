@@ -302,21 +302,37 @@ def state_layout_ctx(cfg: ArchConfig, mesh: Mesh,
         n_dp *= s
     d_pad = _flat_dim(cfg, tp, n_dp, block)
     n_srv, n_outer = n_dp, 1
-    if topology == "hier" and len(dp_axes) > 1:
+    if mesh_topology(mesh, topology, model_axis) == "hier":
         _, _, n_srv, n_outer = pod_split(dp_axes, dp_sizes)
     return StateLayout(d=d_pad, n_dp=n_dp, n_srv=n_srv, n_outer=n_outer,
                        n_segments=_n_segments(cfg, tp, d_pad),
                        dp_sizes=tuple(dp_sizes), tp=tp)
 
 
+def mesh_topology(mesh: Mesh, topology: str = "flat",
+                  model_axis: str = "model") -> str:
+    """The topology the exchange runs on ``mesh``: ``hier`` needs a pod
+    axis (more than one dp axis) and is ``flat`` without one."""
+    dp_axes, _, _ = mesh_axes(mesh, model_axis)
+    return "hier" if topology == "hier" and len(dp_axes) > 1 else "flat"
+
+
+def train_slots(mesh: Mesh, model_axis: str = "model",
+                layout: str = "replicated", topology: str = "flat",
+                optimizer=None):
+    """The optimizer's declared state slots for a run on ``mesh``."""
+    return _as_optimizer(optimizer).state_slots(
+        layout, mesh_topology(mesh, topology, model_axis))
+
+
 def train_state_specs(mesh: Mesh, model_axis: str = "model",
                       layout: str = "replicated",
-                      optimizer=None) -> StateTree:
+                      optimizer=None, topology: str = "flat") -> StateTree:
     """PartitionSpecs for the mesh-global optimizer state, derived from
     the optimizer's declared slots."""
     dp_axes, _, _ = mesh_axes(mesh, model_axis)
-    return state_specs(_as_optimizer(optimizer).state_slots(layout),
-                       dp_axes, model_axis)
+    return state_specs(train_slots(mesh, model_axis, layout, topology,
+                                   optimizer), dp_axes, model_axis)
 
 
 def init_train_state(cfg: ArchConfig, mesh: Mesh,
@@ -329,13 +345,16 @@ def init_train_state(cfg: ArchConfig, mesh: Mesh,
 
     ``topology="hier"`` sizes the server/outer EF chunks by the INNER
     (intra-pod) dp size — the two-level compressed allreduce runs the
-    paper's server stage within the pod only.  The padded flat length is
+    paper's server stage within the pod only — and declares the outer
+    ones only for an optimizer whose compressor needs them
+    (``plan.schedules.needs_outer_ef``).  The padded flat length is
     always a multiple of n_dp_total * block in both topologies.
     ``layout`` selects replicated (paper) / per-dp-rank "local" /
     dp-sharded "zero1" adaptive state.
     """
     ctx = state_layout_ctx(cfg, mesh, model_axis, block, topology)
-    return init_global_state(_as_optimizer(optimizer).state_slots(layout),
+    return init_global_state(train_slots(mesh, model_axis, layout,
+                                         topology, optimizer),
                              ctx, abstract=abstract)
 
 
@@ -486,11 +505,11 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
         ctx = dataclasses.replace(ctx, sp=True)
     tp_axes = (tsc.model_axis,) if tp > 1 else ()
     pspecs = T.param_specs(cfg, tsc.model_axis, tp)
-    osp = train_state_specs(mesh, tsc.model_axis, tsc.layout, optimizer)
+    osp = train_state_specs(mesh, tsc.model_axis, tsc.layout, optimizer,
+                            tsc.topology)
     block = tsc.opt_block_size
 
-    hier = tsc.topology == "hier" and len(dp_axes) > 1
-    if hier:
+    if mesh_topology(mesh, tsc.topology, tsc.model_axis) == "hier":
         inner_axes, outer_axes, _, _ = pod_split(dp_axes, dp_sizes)
     else:
         inner_axes, outer_axes = dp_axes, ()
@@ -555,8 +574,10 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, tsc: TrainStepConfig,
                                  for k in opt})
         # metrics: mean over dp (a no-op while replicated; the honest
         # cross-rank mean in the "local" layout); v_l1 summed over model
-        # shards = the paper's fused-variance norm (Fig. 2)
-        with obs.layer_scope("optimizer", "stats"):
+        # shards = the paper's fused-variance norm (Fig. 2).  Nothing
+        # here but collectives: they are the exchange layer's, with the
+        # optimizer's own exchange
+        with obs.layer_scope("exchange", "metrics"):
             out_metrics = {k: jax.lax.pmean(v, dp_axes) if dp_axes else v
                            for k, v in metrics.items()}
             v_l1 = stats["v_l1"]

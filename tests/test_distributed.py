@@ -986,7 +986,8 @@ class TestPipelinedParity:
                                   topology="hier", pipeline=pipe)
             step = make_train_step(cfg, mesh, tsc, donate=False)
             z = init_train_state(cfg, mesh, block=512, layout="zero1",
-                                 topology="hier")
+                                 topology="hier",
+                                 optimizer=tsc.build_optimizer())
             z = z._replace(v_shard=jnp.ones_like(z.v_shard) * 0.1)
             params = params0
             losses = []

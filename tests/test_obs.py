@@ -313,6 +313,7 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _LAYER_SCOPE = re.compile(
     r"^(?:[A-Za-z_]+\()*obs::(?:model|optimizer|exchange)::[A-Za-z0-9_]+"
     r"\)*$")
+_SERVER = "obs::exchange::server"
 # instructions that execute nothing
 _FREE = ("parameter", "constant", "bitcast", "get-tuple-element", "tuple")
 # the named ops that lie under no layer scope: the layer scan's own loop
@@ -416,8 +417,11 @@ class TestLayerScopes:
         assert len(ops) > 100
         scopes = {src: [p for p in src.split("/") if _LAYER_SCOPE.match(p)]
                   for _, src in ops}
+        # one layer scope each; the server's part of the exchange nests
+        # in the exchange's own scope, and the innermost one counts
         stray = sorted({src for src, found in scopes.items()
-                        if len(found) != 1 and not _UNSCOPED_OK.search(src)})
+                        if len(found) != 1 and found[1:] != [_SERVER]
+                        and not _UNSCOPED_OK.search(src)})
         assert not stray, stray
         # every scope the step opens reaches the compiled program (inside
         # a fusion, where the fusion is named after another instruction)
@@ -429,8 +433,8 @@ class TestLayerScopes:
                 "obs::optimizer::flatten", "obs::optimizer::momentum",
                 "obs::optimizer::update", "obs::optimizer::stats",
                 "obs::optimizer::unflatten"}
-        want.add("obs::exchange::onebit" if stage == "compressed"
-                 else "obs::exchange::allreduce")
+        want |= ({"obs::exchange::onebit", _SERVER} if stage == "compressed"
+                 else {"obs::exchange::allreduce"})
         assert want <= named, want - named
         # forward, backward and the recomputed forward of attention
         attn = [src for src in scopes if "obs::model::attention/" in src]
@@ -438,6 +442,56 @@ class TestLayerScopes:
         assert any(s.startswith("jit(step)/transpose(jvp())/")
                    and "rematted_computation" not in s for s in attn)
         assert any("/rematted_computation/" in s for s in attn)
+
+
+def _innermost_scope(src: str):
+    found = [p for p in src.split("/") if _LAYER_SCOPE.match(p)]
+    return re.search(r"obs::\w+::\w+", found[-1]).group(0) if found \
+        else None
+
+
+def test_dp4_collectives_under_exchange_scopes(tmp_path):
+    """The compressed step over four data-parallel devices: every
+    collective it runs lies under an ``obs::exchange::<part>`` scope, so
+    the benchmark's exchange metrics read all of them, and each chip's
+    server work (the average of the received chunks, the re-compression
+    with the server's error feedback) lies under
+    ``obs::exchange::server``, nested in the exchange's own scope."""
+    hlo = tmp_path / "step.hlo"
+    run_with_devices(f"""
+    import jax, jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as T
+    from repro.train.step import (TrainStepConfig, init_train_state,
+                                  make_train_step)
+    cfg = get_config("bert-base-smoke")
+    mesh = make_mesh((4, 1), ("data", "model"))
+    params = jax.eval_shape(lambda: T.init_params(
+        cfg, jax.random.PRNGKey(0), tp=1))
+    batch = {{k: jax.ShapeDtypeStruct((8, 32), jnp.int32)
+             for k in ("tokens", "labels")}}
+    step = make_train_step(cfg, mesh, TrainStepConfig(
+        stage="compressed", block_size=512), donate=False)
+    opt = init_train_state(cfg, mesh, block=512, abstract=True,
+                           optimizer=step.optimizer)
+    text = step.build(batch).lower(params, opt, batch,
+                                   jnp.float32(1e-3)).compile().as_text()
+    open({str(hlo)!r}, "w").write(text)
+    """, n=4)
+    ops = list(_executed_ops(hlo.read_text()))
+    coll = [(code, src) for code, src in ops if re.match(
+        r"(all-to-all|all-gather|all-reduce|reduce-scatter|"
+        r"collective-permute)", code)]
+    kinds = {re.sub(r"-(start|done)$", "", code) for code, _ in coll}
+    assert {"all-to-all", "all-gather", "all-reduce"} <= kinds, kinds
+    outside = [(code, src) for code, src in coll if not (
+        _innermost_scope(src) or "").startswith("obs::exchange::")]
+    assert not outside, outside
+    server = [src for _, src in ops if _innermost_scope(src) == _SERVER]
+    assert server
+    assert all("/obs::exchange::onebit/" in src for src in server)
+    assert not any(_innermost_scope(src) == _SERVER for _, src in coll)
 
 
 # --------------------------------------------------------------------------

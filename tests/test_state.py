@@ -34,17 +34,54 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 900) -> str:
     return r.stdout
 
 
+def topk_adam() -> TwoStageOptimizer:
+    """1-bit Adam over a sparse compressor: the one kind of optimizer
+    whose hierarchical exchange runs the cross-pod EF loops."""
+    return get_optimizer("onebit_adam", compressor="topk",
+                         compressor_kwargs={"block_size": 256, "ratio": 8})
+
+
 class TestSlotRegistry:
     def test_base_family_slots(self):
         opt = TwoStageOptimizer()
         names = [s.name for s in opt.state_slots()]
         assert names == ["m", "v", "worker_err", "server_err", "scale",
-                         "count", "v_step", "outer_err", "outer_ag_err"]
+                         "count", "v_step"]
         by = {s.name: s for s in opt.state_slots()}
         assert by["worker_err"].ef == "worker"
         assert by["server_err"].bucket_keyed
-        assert by["outer_ag_err"].chunk_of == "total"
         assert by["count"].dtype == "int32"
+        # the hierarchical schedule of a sparse compressor consumes the
+        # cross-pod EF slots: declared there and nowhere else
+        hier = {s.name: s for s in topk_adam().state_slots("replicated",
+                                                           "hier")}
+        assert list(hier)[len(names):] == ["outer_err", "outer_ag_err"]
+        assert hier["outer_ag_err"].chunk_of == "total"
+
+    @pytest.mark.parametrize("optimizer,compressor,topology,outer", [
+        ("onebit_adam", "onebit", "flat", False),
+        ("onebit_adam", "onebit", "hier", False),
+        ("onebit_adam", "topk", "flat", False),
+        ("onebit_adam", "topk", "hier", True),
+        ("zerone_adam", "onebit", "hier", False),
+        ("onebit_lamb", "identity", "hier", False)])
+    def test_outer_slots_only_where_consumed(self, optimizer, compressor,
+                                             topology, outer):
+        """A state holds the cross-pod EF slots only where its schedule
+        consumes them (``plan.schedules.needs_outer_ef`` on ``hier``);
+        elsewhere they would be full-length zeros the step never reads."""
+        from repro.plan import needs_outer_ef
+        opt = get_optimizer(optimizer, compressor=compressor,
+                            compressor_kwargs={"block_size": 256})
+        both = {"outer_err", "outer_ag_err"}
+        for layout in LAYOUTS:
+            names = {s.name for s in opt.state_slots(layout, topology)}
+            assert names & both == (both if outer else set())
+        assert outer == (topology == "hier"
+                         and needs_outer_ef(opt.compressor))
+        st = opt.init_state(4096, 8, n_inner=4 if topology == "hier"
+                            else None)
+        assert ("outer_err" in st and "outer_ag_err" in st) is outer
 
     def test_zero1_layout_swaps_v_for_shards(self):
         by = {s.name: s for s in TwoStageOptimizer().state_slots("zero1")}
@@ -214,7 +251,10 @@ class TestGlobalMaterialisation:
         assert st.v.shape == (1, d)
         assert st.worker_err.shape == (2, 2, 1, d)
         assert st.server_err.shape == (2, 2, 1, d // 2)   # inner size 2
-        assert st.outer_ag_err.shape == (2, 2, 1, d // 4)
+        outer = init_global_state(
+            topk_adam().state_slots("replicated", "hier"), ctx)
+        assert outer.outer_err.shape == (2, 2, 1, d // 2)
+        assert outer.outer_ag_err.shape == (2, 2, 1, d // 4)
         assert st.scale.shape == (1, S)
         assert st.count.shape == () and st.count.dtype == jnp.int32
         sp = state_specs(opt.state_slots("replicated"), ("pod", "data"))
@@ -256,8 +296,9 @@ class TestCheckpointMigration:
     def test_pre_plan_ir_namedtuple_checkpoint_loads(self):
         """Regression (satellite): a pre-PR2-era checkpoint — NamedTuple
         state container, no outer EF slots — must load into the
-        registry-built template with the missing slots named from the
-        slot diff and zero-initialised."""
+        registry-built template of a run that declares them (hier over
+        a sparse compressor) with the missing slots named from the slot
+        diff and zero-initialised."""
         class PrePlanIRState(NamedTuple):   # the PR-1-era container
             m: object
             v: object
@@ -282,7 +323,7 @@ class TestCheckpointMigration:
         params = {"w": rng.normal(size=(4,)).astype(np.float32)}
         ctx = StateLayout(d=d, n_dp=n, n_srv=n, n_segments=3,
                           dp_sizes=(n,), tp=1)
-        slots = opt.state_slots()
+        slots = topk_adam().state_slots("replicated", "hier")
         from repro.state import init_global_state
         template = init_global_state(slots, ctx)
         with tempfile.TemporaryDirectory() as tmp:
@@ -300,6 +341,56 @@ class TestCheckpointMigration:
                                       np.zeros((n, 1, d // n)))
         np.testing.assert_array_equal(np.asarray(st.outer_ag_err),
                                       np.zeros((n, 1, d // n)))
+
+    @staticmethod
+    def _outer_ckpt(tmp, save_slots, load_slots, **fill):
+        """Saves a zero state of ``save_slots`` with ``fill`` written
+        over some slots, and loads it into a zero state of
+        ``load_slots``; returns (saved, loaded)."""
+        from repro.state import (init_global_state, load_train_state,
+                                 save_train_state)
+        ctx = StateLayout(d=2048, n_dp=4, n_srv=4, dp_sizes=(4,), tp=1)
+        st = init_global_state(save_slots, ctx)
+        st = st._replace(**{k: jnp.full(st[k].shape, v)
+                            for k, v in fill.items()})
+        path = os.path.join(tmp, "ck.npz")
+        save_train_state(path, {"w": np.zeros(2)}, st, 9, slots=save_slots,
+                         ctx=ctx, n_buckets=1, block=64)
+        (_, got), step = load_train_state(
+            path, {"w": np.zeros(2)}, init_global_state(load_slots, ctx),
+            slots=load_slots, ctx=ctx, n_buckets=1, block=64)
+        assert step == 9
+        assert list(got) == [s.name for s in load_slots]
+        return st, got
+
+    def test_outer_slots_dropped_and_made(self):
+        """A checkpoint that holds zero cross-pod EF slots (every state
+        declared them before they became topology-dependent) loads,
+        without a word, into a flat 1-bit run's state, which lacks them;
+        the reverse load makes them, as zeros, and names them."""
+        import warnings
+        flat = TwoStageOptimizer().state_slots()
+        hier = topk_adam().state_slots("replicated", "hier")
+        with tempfile.TemporaryDirectory() as tmp:
+            with warnings.catch_warnings():
+                warnings.simplefilter("error")
+                st, got = self._outer_ckpt(tmp, hier, flat, m=0.5,
+                                           server_err=0.25)
+            np.testing.assert_array_equal(got.m, np.asarray(st.m))
+            np.testing.assert_array_equal(got.server_err,
+                                          np.asarray(st.server_err))
+            with pytest.warns(UserWarning, match="outer_ag_err"):
+                _, got = self._outer_ckpt(tmp, flat, hier, worker_err=1.0)
+        np.testing.assert_array_equal(got.worker_err, 1.0)
+        np.testing.assert_array_equal(got.outer_err, 0.0)
+        np.testing.assert_array_equal(got.outer_ag_err, 0.0)
+
+    def test_nonzero_outer_slots_dropped_with_a_warning(self):
+        flat = TwoStageOptimizer().state_slots()
+        hier = topk_adam().state_slots("replicated", "hier")
+        with tempfile.TemporaryDirectory() as tmp:
+            with pytest.warns(UserWarning, match=r"\['outer_err'\]"):
+                self._outer_ckpt(tmp, hier, flat, outer_err=0.5)
 
     def test_save_canonical_load_rebuckets(self):
         """save under 4 buckets -> the archive holds the canonical
@@ -365,11 +456,13 @@ class TestCheckpointMigration:
 
 class TestLayoutManifest:
     def test_manifest_deterministic_and_complete(self):
-        opt = TwoStageOptimizer()
+        opt = topk_adam()
         ctx = StateLayout(d=1 << 16, n_dp=8, n_srv=4, n_outer=2,
                           n_segments=4, dp_sizes=(2, 4), tp=1)
-        m1 = layout_manifest(opt.state_slots("zero1"), ctx, block=1024)
-        m2 = layout_manifest(opt.state_slots("zero1"), ctx, block=1024)
+        m1 = layout_manifest(opt.state_slots("zero1", "hier"), ctx,
+                             block=1024)
+        m2 = layout_manifest(opt.state_slots("zero1", "hier"), ctx,
+                             block=1024)
         assert json.dumps(m1, sort_keys=True) == json.dumps(m2,
                                                             sort_keys=True)
         names = [row["name"] for row in m1["slots"]]
@@ -652,7 +745,7 @@ class TestDistributedStateInvariance:
             assert [r["loss"] for r in ref_h] == \
                 [r["loss"] for r in h]
             c = canon(o, nb)
-            for name in ("server_err", "outer_err", "outer_ag_err"):
+            for name in (s.name for s in slots if s.bucket_keyed):
                 np.testing.assert_array_equal(np.asarray(ref_c[name]),
                                               np.asarray(c[name]))
             print("OK resume bitwise pipeline=", pipe)
