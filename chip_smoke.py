@@ -34,6 +34,7 @@ JSON object, printed only when every phase passed.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import sys
@@ -59,6 +60,7 @@ BATCH_FOUR_CHIPS = 128
 # the wire format is checked bit for bit on its own (``wire_check``).
 LOSS_RTOL = 2e-4
 WIRE_LEN = 1 << 24
+SERVER_CHUNK = 4099 * 4096     # whole blocks, a partial last 1,024-row grid step
 # 1-bit compressed losses: three steps at a warmup learning rate
 # (<= 6e-5) move a loss of ~10.8 by a few percent at most unless the
 # exchange is broken (a divergence, a wrong scale, a lost residual).  The
@@ -181,6 +183,7 @@ def one_chip() -> None:
         raise AssertionError(f"pallas losses {pallas_losses} differ from "
                              f"jnp losses {jnp_losses}")
     wire_check()
+    server_check()
 
 
 def wire_check() -> None:
@@ -215,6 +218,41 @@ def wire_check() -> None:
     if n_bad or scale_rel > 1e-6 or de_rel > 1e-6 or err_abs > 1e-6:
         raise AssertionError("the fused 1-bit kernel's wire output differs "
                              "from the jnp path's")
+
+
+def server_check() -> None:
+    """The flat schedule's server step on ``n`` received chunks of
+    ``SERVER_CHUNK`` elements (a partial last grid step), the server
+    kernel against the jnp decompress, mean and compress, with the
+    server error donated as the train step donates it: the same bitmap,
+    the scales and residual up to summation order."""
+    import jax
+    import numpy as np
+    from repro.optim.compressors import Compressor, OneBitCompressor
+    comp = OneBitCompressor()
+    steps = {"jnp": functools.partial(Compressor.server_ef_compress, comp),
+             "pallas": comp.server_ef_compress}
+    for n in (1, 4):
+        kx, ke = jax.random.split(jax.random.PRNGKey(n))
+        recv = jax.jit(jax.vmap(comp.compress))(
+            jax.random.normal(kx, (n, SERVER_CHUNK)))
+        out = {}
+        for tag, step in steps.items():
+            err = 0.1 * jax.random.normal(ke, (SERVER_CHUNK,))
+            fn = jax.jit(lambda r, e: step(r, e, "mean"), donate_argnums=1)
+            (pk, sc), new_err = fn(recv, err)
+            out[tag] = [np.asarray(a) for a in (pk, sc, new_err)]
+        (pk_j, sc_j, err_j), (pk_k, sc_k, err_k) = out["jnp"], out["pallas"]
+        n_bad = int(np.sum(pk_j != pk_k))
+        scale_rel = float(np.max(np.abs(sc_k - sc_j) / np.abs(sc_j)))
+        err_abs = float(np.max(np.abs(err_k - err_j)))
+        print(f"server check (n {n}, {SERVER_CHUNK} elements): {n_bad} "
+              f"bitmap bytes differ; scales max relative difference "
+              f"{scale_rel:.3e}; residual max absolute difference "
+              f"{err_abs:.3e}", flush=True)
+        if n_bad or scale_rel > 1e-6 or err_abs > 1e-6:
+            raise AssertionError("the server kernel's output differs from "
+                                 "the jnp server step's")
 
 
 def four_chips(devices) -> None:

@@ -70,6 +70,21 @@ class Compressor:
             return payload, jnp.zeros_like(buf)
         return payload, buf - self.decompress(payload)
 
+    def combine_received(self, recv: Payload, combine: str) -> jax.Array:
+        """The ``n`` chunks a rank received (each leaf ``(n, ...)``),
+        decompressed and averaged (``"mean"``) or summed (``"sum"``)."""
+        vals = jax.vmap(lambda *leaves: self.decompress(tuple(leaves)))(
+            *recv)
+        if combine == "mean":
+            return jnp.mean(vals, axis=0)
+        return jnp.sum(vals, axis=0)
+
+    def server_ef_compress(self, recv: Payload, err: jax.Array,
+                           combine: str) -> Tuple[Payload, jax.Array]:
+        """The flat schedule's server step: ``ef_compress`` of the
+        combined received chunks with the server error ``err``."""
+        return self.ef_compress(self.combine_received(recv, combine), err)
+
     def compress(self, x: jax.Array) -> Payload:
         raise NotImplementedError
 
@@ -137,6 +152,27 @@ class OneBitCompressor(Compressor):
         packed, scales = payload
         return decompress_onebit(packed, scales, self.block_size,
                                  self.use_kernel)
+
+    def server_ef_compress(self, recv, err, combine):
+        """Lowered for a TPU, with blocks of whole 128-lane rows, the
+        server step is one Pallas sweep (``kernels/onebit``: the server
+        error read and written once), whatever ``use_kernel`` says;
+        elsewhere it is the base class's decompress, combine and
+        ``ef_compress``.  ``use_kernel`` only tells the sweep that one
+        chunk's bitmap comes from the Pallas codec."""
+        def chain(packed, scales, e):
+            (pk, sc), new_err = Compressor.server_ef_compress(
+                self, (packed, scales), e, combine)
+            return pk, sc, new_err
+        if self.block_size % 128:
+            pk, sc, new_err = chain(*recv, err)
+        else:
+            from repro.kernels.onebit import kernel as _kernel
+            pk, sc, new_err = _kernel.ef_server_fused(
+                *recv, err, self.block_size, combine,
+                byte_rows=self.use_kernel or recv[0].shape[0] > 1,
+                cpu=chain)
+        return (pk, sc), new_err
 
     def wire_specs(self, d):
         return (WireSpec("uint8", (d // 8,)),

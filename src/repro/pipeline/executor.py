@@ -60,7 +60,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.plan.executor import Errs, execute_op
+from repro.plan.executor import Errs, execute_op, op_after
 
 from repro.pipeline.ir import PipelinedPlan
 
@@ -132,11 +132,12 @@ def execute_pipelined(pplan: PipelinedPlan, comp, value,
     # wavefront issue: stage s of bucket t-s at tick t — ops of one tick
     # are mutually independent, the overlap surface for the scheduler
     for b, s in pplan.issue_order(order):
-        op = pplan.buckets[b].plan.ops[s]
-        vals[b], bucket_errs[b] = execute_op(op, comp, vals[b],
+        ops = pplan.buckets[b].plan.ops
+        vals[b], bucket_errs[b] = execute_op(ops[s], comp, vals[b],
                                              bucket_errs[b],
                                              plan_name=pplan.name,
-                                             stage=s, bucket=b)
+                                             stage=s, bucket=b,
+                                             next_op=op_after(ops, s))
 
     out = vals[0] if pplan.n_buckets == 1 else jnp.concatenate(vals)
     new_errs = dict(errs)

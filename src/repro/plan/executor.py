@@ -22,7 +22,12 @@ against the compiled HLO).
 Numerics are bit-for-bit the pre-IR inline schedules of
 ``repro.core.comm``: chunk exchange is ``all_to_all`` per payload leaf +
 vmapped decompress + ``jnp.mean``; gather is tiled ``all_gather`` per
-leaf + decompress (see tests/test_distributed.py parity tests).
+leaf + decompress (see tests/test_distributed.py parity tests).  In the
+flat schedule the received chunks reach the server's all_gather still
+compressed (:class:`Received`), and the compressor's
+``server_ef_compress`` combines and re-compresses them: the same
+decompress, mean and ``ef_compress`` by default, one Pallas sweep for
+1-bit lowered for a TPU.
 
 When trace spans are enabled (``repro.obs.trace.set_tracing``), every
 op lowers inside a ``jax.named_scope`` carrying its
@@ -38,7 +43,7 @@ under the ``obs::exchange::server`` layer scope (``server_scope``).
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -94,17 +99,30 @@ def server_scope():
     return obs.layer_scope("exchange", "server")
 
 
-def _exec_all_to_all(op: AllToAll, comp, value, errs):
+class Received(NamedTuple):
+    """The chunks an all_to_all received, left compressed (each leaf
+    ``(n, ...)``) for the server step that follows it."""
+    payload: Tuple[jax.Array, ...]
+    combine: str
+
+
+def _feeds_server(op: CollectiveOp, next_op: Optional[CollectiveOp]) -> bool:
+    """True for the flat schedule's all_to_all, whose received chunks go
+    straight to the server all_gather over the same axes: the compressor
+    then combines and re-compresses them in one ``server_ef_compress``."""
+    return (isinstance(op, AllToAll) and bool(op.axes)
+            and isinstance(next_op, AllGather)
+            and next_op.err_slot == "server" and next_op.axes == op.axes)
+
+
+def _exec_all_to_all(op: AllToAll, comp, value, errs, to_server=False):
     payload, errs = _compress(op, comp, value, errs)
     if op.axes:
-        recv = [chunk_all_to_all(p, op.n, op.axes) for p in payload]
+        recv = tuple(chunk_all_to_all(p, op.n, op.axes) for p in payload)
+        if to_server:
+            return Received(recv, op.combine), errs
         with server_scope():
-            vals = jax.vmap(lambda *leaves: comp.decompress(tuple(leaves)))(
-                *recv)
-            if op.combine == "mean":
-                value = jnp.mean(vals, axis=0)
-            else:
-                value = jnp.sum(vals, axis=0)
+            value = comp.combine_received(recv, op.combine)
     else:
         # degenerate single-group: the compress/decompress roundtrip still
         # runs so single-device numerics match the distributed path
@@ -115,7 +133,13 @@ def _exec_all_to_all(op: AllToAll, comp, value, errs):
 
 def _exec_all_gather(op: AllGather, comp, value, errs):
     with server_scope():
-        payload, errs = _compress(op, comp, value, errs)
+        if isinstance(value, Received):
+            payload, new_err = comp.server_ef_compress(
+                value.payload, errs[op.err_slot], value.combine)
+            _check_payload(op, payload)
+            errs = {**errs, op.err_slot: new_err}
+        else:
+            payload, errs = _compress(op, comp, value, errs)
     if op.axes:
         out = tuple(jax.lax.all_gather(p, op.axes, tiled=op.tiled)
                     for p in payload)
@@ -172,14 +196,20 @@ def scoped_op_names(plan: CommPlan) -> Tuple[str, ...]:
                  for s, op in enumerate(plan.ops))
 
 
-def execute_op(op: CollectiveOp, comp, value: jax.Array, errs: Errs,
+def execute_op(op: CollectiveOp, comp, value, errs: Errs,
                plan_name: str = "plan", stage: int = 0,
-               bucket: Optional[int] = None) -> Tuple[jax.Array, Errs]:
+               bucket: Optional[int] = None,
+               next_op: Optional[CollectiveOp] = None) -> Tuple[object, Errs]:
     """Lower ONE collective op (the public entry the pipelined executor
     in :mod:`repro.pipeline.executor` steps through in wavefront order).
+    ``next_op`` is the op that consumes the result: where it is the
+    flat schedule's server all_gather, the result is the
+    :class:`Received` chunks, which only that op takes.
     ``plan_name``/``stage``/``bucket`` only label the op's trace span
     when tracing is on — they never change the lowering."""
     with op_scope(plan_name, stage, op, bucket):
+        if _feeds_server(op, next_op):
+            return _exec_all_to_all(op, comp, value, errs, to_server=True)
         return _EXEC[type(op)](op, comp, value, errs)
 
 
@@ -196,6 +226,10 @@ def execute_plan(plan: CommPlan, comp, value: jax.Array,
     assert not missing, f"plan {plan.name!r} needs EF slots {missing}"
     assert value.shape == (plan.d,), (value.shape, plan.d)
     for stage, op in enumerate(plan.ops):
-        with op_scope(plan.name, stage, op):
-            value, errs = _EXEC[type(op)](op, comp, value, errs)
+        value, errs = execute_op(op, comp, value, errs, plan.name, stage,
+                                 next_op=op_after(plan.ops, stage))
     return value, errs
+
+
+def op_after(ops, stage: int) -> Optional[CollectiveOp]:
+    return ops[stage + 1] if stage + 1 < len(ops) else None

@@ -17,6 +17,7 @@ from hypothesis import strategies as st
 from repro.core import compression as C
 from repro.kernels.fused_adam import ops as fa_ops
 from repro.kernels.fused_adam import ref as fa_ref
+from repro.kernels.onebit import kernel as ob_kernel
 from repro.kernels.onebit import ops as ob_ops
 from repro.kernels.onebit import ref as ob_ref
 
@@ -81,6 +82,61 @@ class TestOneBitKernel:
         y = C.ef_decompress(payload, cfg)
         np.testing.assert_allclose(np.asarray(y + new_e), np.asarray(x + e),
                                    rtol=1e-5, atol=1e-6)
+
+
+def _received(n, chunk, block, seed):
+    """``n`` compressed chunks as a server receives them from the
+    all_to_all: ``(n, chunk/8)`` u8 and ``(n, chunk/block)`` f32."""
+    xs = jnp.stack([rand(chunk, seed + i) for i in range(n)])
+    return jax.vmap(lambda x: C.compress_onebit(x, block))(xs)
+
+
+class TestOneBitServerKernel:
+    """``ef_server_fused`` (interpreted) against the server step it
+    replaces on the chip: decompress, combine and EF-compress in jnp."""
+
+    # 1,280 rows of 128 lanes: a grid step holds 1,024, so the last one
+    # is partial at every block size
+    CHUNK = 1280 * 128
+
+    def _check(self, n, combine, block, chunk, seed=0, byte_rows=None):
+        from repro.optim.compressors import Compressor, OneBitCompressor
+        packed, scales = _received(n, chunk, block, seed)
+        err = rand(chunk, seed + n, 0.3)
+        pk_k, sc_k, ne_k = ob_kernel.ef_server_fused(
+            packed, scales, err, block, combine, byte_rows=byte_rows)
+        comp = OneBitCompressor(block_size=block)
+        (pk_j, sc_j), ne_j = Compressor.server_ef_compress(
+            comp, (packed, scales), err, combine)
+        np.testing.assert_array_equal(np.asarray(pk_k), np.asarray(pk_j))
+        np.testing.assert_allclose(np.asarray(sc_k), np.asarray(sc_j),
+                                   rtol=1e-6)
+        # the residual is relative to its block's scale, not to itself
+        atol = 1e-6 * float(jnp.max(sc_j))
+        np.testing.assert_allclose(np.asarray(ne_k), np.asarray(ne_j),
+                                   rtol=1e-6, atol=atol)
+        # error feedback: the payload sent on plus the new error is the
+        # server's buffer, the combined chunks plus the old error
+        buf = comp.combine_received((packed, scales), combine) + err
+        np.testing.assert_allclose(
+            np.asarray(comp.decompress((pk_k, sc_k)) + ne_k),
+            np.asarray(buf), rtol=1e-6, atol=atol)
+
+    @pytest.mark.parametrize("block", [128, 1024, 4096])
+    @pytest.mark.parametrize("combine", ["mean", "sum"])
+    @pytest.mark.parametrize("n", [1, 4])
+    def test_matches_jnp_server_step(self, n, combine, block):
+        self._check(n, combine, block, self.CHUNK)
+
+    def test_one_chunk_as_byte_rows(self):
+        """One chunk whose bitmap comes from the Pallas codec: rows of 128
+        bytes, the view a group of more than one takes."""
+        self._check(1, "mean", 4096, self.CHUNK, seed=5, byte_rows=True)
+
+    def test_chunk_of_a_few_blocks(self):
+        """Three blocks of 128: one grid step of three rows, the whole
+        chunk, with no row of 1,024 elements filled."""
+        self._check(2, "mean", 128, 3 * 128, seed=11)
 
 
 class TestFusedAdamKernel:
@@ -426,6 +482,9 @@ class TestPlatformChoice:
         "onebit": lambda x: ob_ops.ef_compress_fused(x, x, 1024),
         "onebit_decompress": lambda x: ob_ops.decompress(
             x[:512].astype(jnp.uint8), x[:4], 1024),
+        "onebit_server": lambda x: ob_kernel.ef_server_fused(
+            x[:1024].astype(jnp.uint8).reshape(2, 512), x[:8].reshape(2, 4),
+            x, 1024),
         "fused_adam": lambda x: fa_ops.adam_step(x, x, x, x, 1e-3),
     }
 

@@ -186,6 +186,64 @@ class TestExecutorParity:
                                       np.asarray(extra))
 
 
+class TestServerStep:
+    """The flat schedule's all_to_all hands its received chunks, still
+    compressed, to the server's all_gather, which runs the compressor's
+    ``server_ef_compress``: serially and in each pipelined bucket alike.
+    The hierarchical schedule's server gets an averaged f32 value."""
+
+    @pytest.mark.parametrize("schedule,n_buckets,calls", [
+        ("flat", 1, 1), ("flat", 3, 3), ("hier", 1, 0), ("hier", 3, 0)])
+    def test_flat_server_goes_through_the_compressor(
+            self, monkeypatch, schedule, n_buckets, calls):
+        import jax
+        from jax.sharding import Mesh
+        from jax.sharding import PartitionSpec as P
+        from repro.optim.compressors import OneBitCompressor
+        seen = []
+        server = OneBitCompressor.server_ef_compress
+
+        def spy(self, recv, err, combine):
+            seen.append((tuple(p.shape for p in recv), err.shape, combine))
+            return server(self, recv, err, combine)
+        monkeypatch.setattr(OneBitCompressor, "server_ef_compress", spy)
+
+        comp = get_compressor("onebit", block_size=BLOCK)
+        d = BLOCK * 12
+        if schedule == "flat":
+            mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+            plan = flat_schedule(comp, d, 1, ("data",))
+        else:
+            mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                        ("pod", "data"))
+            plan = hier_schedule(comp, d, 1, 1, ("data",), ("pod",))
+        errs = {"worker": rand(d, 2, .1), "server": rand(d, 3, .1)}
+
+        def run(pipelined):
+            def body(x, e):
+                if not pipelined:
+                    return execute_plan(plan, comp, x, e)
+                pp = lower_to_pipelined(plan, comp, Bucketer.for_exchange(
+                    d, 1, BLOCK, n_buckets))
+                return execute_pipelined(pp, comp, x, e)
+            return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                                         out_specs=P(), check_vma=False))(
+                rand(d, 1), errs)
+
+        o1, e1 = run(pipelined=False)
+        assert len(seen) == min(calls, 1)
+        seen.clear()
+        o2, e2 = run(pipelined=True)
+        assert len(seen) == calls
+        size = d // n_buckets
+        assert all(s == (((1, size // 8), (1, size // BLOCK)), (size,),
+                         "mean") for s in seen)
+        np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+        for slot in errs:
+            np.testing.assert_array_equal(np.asarray(e1[slot]),
+                                          np.asarray(e2[slot]))
+
+
 class TestPipelinedCost:
     def _hier(self, d=1 << 27, block=4096):
         comp = get_compressor("onebit", block_size=block)

@@ -131,18 +131,25 @@ class TestSignPack:
 
 
 def _train_step(topo, arch, batch: int, seq: int, stage: str,
-                use_kernel: bool = False, chips: int = 1):
+                use_kernel: bool = False, chips: int = 1, pods: int = 1,
+                **step_kw):
     """The donated step program of ``launch.train`` on ``chips``
-    described chips (data parallel), from shapes only; ``arch`` is a
-    registered name or a config, ``batch`` the global batch."""
+    described chips (data parallel, over ``pods`` pods), from shapes
+    only; ``arch`` is a registered name or a config, ``batch`` the global
+    batch, ``step_kw`` further ``TrainStepConfig`` fields."""
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.train.step import (TrainStepConfig, init_train_state,
                                   make_train_step)
     cfg = get_config(arch) if isinstance(arch, str) else arch
-    mesh = Mesh(np.array(topo.devices[:chips]).reshape(chips, 1),
-                ("data", "model"))
-    tsc = TrainStepConfig(stage=stage, use_kernel=use_kernel)
+    devices = np.array(topo.devices[:chips])
+    if pods > 1:
+        dp = ("pod", "data")
+        mesh = Mesh(devices.reshape(pods, chips // pods, 1), dp + ("model",))
+    else:
+        dp = "data"
+        mesh = Mesh(devices.reshape(chips, 1), ("data", "model"))
+    tsc = TrainStepConfig(stage=stage, use_kernel=use_kernel, **step_kw)
     fn = make_train_step(cfg, mesh, tsc)
 
     def shapes(tree, specs):
@@ -153,9 +160,9 @@ def _train_step(topo, arch, batch: int, seq: int, stage: str,
 
     params = jax.eval_shape(lambda k: T.init_params(cfg, k, tp=1),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
-    opt = init_train_state(cfg, mesh, abstract=True,
+    opt = init_train_state(cfg, mesh, abstract=True, topology=tsc.topology,
                            optimizer=tsc.build_optimizer())
-    rows = NamedSharding(mesh, P("data", None))
+    rows = NamedSharding(mesh, P(dp, None))
     data = {k: jax.ShapeDtypeStruct((batch, seq), dt, sharding=rows)
             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
                           ("loss_mask", jnp.float32))}
@@ -171,6 +178,12 @@ def _train_step(topo, arch, batch: int, seq: int, stage: str,
 # the optimizer's Pallas kernels, by the jitted calls they are named after
 OPTIMIZER_KERNELS = ("jit(_adam_call)", "jit(_ef_compress_call)",
                      "jit(_decompress_call)")
+# the flat exchange's server step, by its kernel's name: in every
+# compressed 1-bit program with the flat schedule, use_kernel or not
+SERVER_KERNEL = "onebit_server"
+# each compressed jnp-codec program's peak (GiB) with the server step
+# in XLA's fusions, which materialise the average and the server buffer
+SERVER_IN_XLA_PEAK_GIB = {1: 12.22, 4: 10.20}
 
 
 # each program's peak (GiB) when every run also carried the hierarchical
@@ -206,11 +219,15 @@ def test_bert_large_step_fits_one_chip(topo, stage, use_kernel):
         PEAK_WITH_OUTER_SLOTS_GIB[stage, use_kernel] - 2.5) * 2**30
     if stage == "compressed":
         assert ma.peak_memory_in_bytes + HELD_BYTES <= HBM_BYTES
+    if stage == "compressed" and not use_kernel:
+        assert ma.peak_memory_in_bytes <= SERVER_IN_XLA_PEAK_GIB[1] * 2**30
     text = compiled.as_text()
     # sequence 128 is below FLASH_MIN_SEQ, so attention goes through XLA;
-    # the optimizer's kernels are there with use_kernel only
+    # the optimizer's kernels are there with use_kernel only, the server
+    # kernel in every compressed step
     assert "flash_attn" not in text
     assert any(k in text for k in OPTIMIZER_KERNELS) == use_kernel
+    assert (SERVER_KERNEL in text) == (stage == "compressed")
     # a minute would mean the compressed exchange fell back into the
     # pathological layouts the flat-vector views avoid
     assert seconds < 120
@@ -230,8 +247,25 @@ def test_bert_large_dp4_step_fits_beside_held_momentum(topo):
     # error of its quarter of the vector
     assert 0 <= ma.argument_size_in_bytes - 4.25 * 4 * BERT_LARGE_D < 2**20
     assert ma.peak_memory_in_bytes + HELD_BYTES <= HBM_BYTES
+    assert ma.peak_memory_in_bytes <= SERVER_IN_XLA_PEAK_GIB[4] * 2**30
     text = compiled.as_text()
     assert "all-to-all" in text and "all-gather" in text
+    assert SERVER_KERNEL in text
+
+
+@pytest.mark.parametrize("compressor,pods,fused", [
+    ("onebit", 1, True), ("topk", 1, False), ("onebit", 2, False)])
+def test_server_kernel_on_the_flat_onebit_exchange_only(topo, compressor,
+                                                        pods, fused):
+    """The server kernel replaces the flat schedule's 1-bit server step
+    alone: a sparse compressor and the hierarchical schedule (two pods
+    of two chips) keep the decompress, mean and compress of XLA."""
+    compiled, _ = _train_step(topo, "bert-base-smoke", 8, 64, "compressed",
+                              chips=4, pods=pods, compressor=compressor,
+                              topology="hier" if pods > 1 else "flat")
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    assert (SERVER_KERNEL in text) == fused
 
 
 def test_bert_base_step_keeps_scores_in_vmem(topo):
